@@ -15,4 +15,3 @@ against:
                pair-aware, replica-group byte attribution), overlap_fraction
                + roofline
 """
-from repro.util import jaxcompat as _jaxcompat  # noqa: F401  (installs shims)

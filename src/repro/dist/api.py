@@ -27,8 +27,6 @@ import contextlib
 import contextvars
 from typing import Iterable, Optional
 
-from repro.util import jaxcompat as _jaxcompat  # noqa: F401  (installs shims)
-
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 

@@ -78,8 +78,6 @@ import os
 import time
 from typing import Iterable, Optional, Tuple
 
-from repro.util import jaxcompat as _jaxcompat  # noqa: F401  (installs shims)
-
 import jax
 import jax.numpy as jnp
 from jax import lax

@@ -18,8 +18,6 @@ from __future__ import annotations
 
 from typing import Iterable
 
-from repro.util import jaxcompat as _jaxcompat  # noqa: F401  (installs shims)
-
 import jax
 import jax.numpy as jnp
 from jax import lax

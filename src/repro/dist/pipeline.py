@@ -45,8 +45,6 @@ import dataclasses
 import functools
 from typing import Callable, Dict, Optional, Tuple, Union
 
-from repro.util import jaxcompat as _jaxcompat  # noqa: F401  (installs shims)
-
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -22,9 +22,6 @@ dims from the END so they apply to both stacked and unstacked leaves.
 """
 from __future__ import annotations
 
-
-from repro.util import jaxcompat as _jaxcompat  # noqa: F401  (installs shims)
-
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 

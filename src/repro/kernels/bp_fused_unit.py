@@ -80,6 +80,24 @@ def _kernel(g_ref, w_ref, x_ref, z_ref, lr_ref, go_ref, wo_ref, acc_ref,
         wo_ref[...] = maybe_kq(w - lr_ref[0] * acc_ref[...], w_out_bits)
 
 
+def _quantize_resident_w(w, wq_ref, sw_ref, w_spec_static):
+    """Master W -> int8 payload, once per frame (loop-invariant): on its
+    (I,F)-derived grid when the format embeds (``w_spec_static``), else
+    absmax over the resident W (block-scaled transport of a too-wide
+    format).  s_w is kept as a (1, 1) vector: Mosaic stores no scalar to
+    VMEM."""
+    if w_spec_static is not None:
+        s_w = jnp.full((1, 1), w_spec_static.scale, jnp.float32)
+        wq_ref[...] = jnp.clip(jnp.round(w / s_w), w_spec_static.qmin,
+                               w_spec_static.qmax).astype(jnp.int8)
+    else:
+        am = jnp.max(jnp.abs(w), keepdims=True)
+        s_w = jnp.where(am > 0, am / 127.0, jnp.float32(1.0))
+        wq_ref[...] = jnp.clip(jnp.round(w / s_w), -127,
+                               127).astype(jnp.int8)
+    sw_ref[...] = s_w
+
+
 def _kernel_int8(g_ref, w_ref, x_ref, z_ref, meta_ref, go_ref, wo_ref,
                  acc_ref, wq_ref, sw_ref, *, n_k: int, g_bits, w_bits,
                  w_out_bits, act: str, w_spec_static):
@@ -88,24 +106,11 @@ def _kernel_int8(g_ref, w_ref, x_ref, z_ref, meta_ref, go_ref, wo_ref,
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        # master W -> int8 payload, once per frame (loop-invariant): on its
-        # (I,F)-derived grid when the format embeds (w_spec_static), else
-        # absmax over the resident W (block-scaled transport of a too-wide
-        # format)
         w = w_ref[...].astype(jnp.float32)
-        if w_spec_static is not None:
-            s_w = jnp.float32(w_spec_static.scale)
-            wq_ref[...] = jnp.clip(jnp.round(w / s_w), w_spec_static.qmin,
-                                   w_spec_static.qmax).astype(jnp.int8)
-        else:
-            am = jnp.max(jnp.abs(w))
-            s_w = jnp.where(am > 0, am / 127.0, jnp.float32(1.0))
-            wq_ref[...] = jnp.clip(jnp.round(w / s_w), -127,
-                                   127).astype(jnp.int8)
-        sw_ref[0, 0] = s_w
+        _quantize_resident_w(w, wq_ref, sw_ref, w_spec_static)
 
     go = (int8_dot(g_ref[...], wq_ref[...], _GW_DIMS).astype(jnp.float32)
-          * (meta_ref[0] * sw_ref[0, 0]))              # s_g * s_w
+          * (meta_ref[0] * sw_ref[...]))              # s_g * s_w
     go = go * act_deriv(z_ref[...].astype(jnp.float32), act)
     go_ref[...] = maybe_kq(go, g_bits)
 
@@ -169,21 +174,12 @@ def _kernel_db_int8(g_hbm, w_ref, x_hbm, z_hbm, meta_ref, go_ref, wo_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         w = w_ref[...].astype(jnp.float32)
-        if w_spec_static is not None:
-            s_w = jnp.float32(w_spec_static.scale)
-            wq_ref[...] = jnp.clip(jnp.round(w / s_w), w_spec_static.qmin,
-                                   w_spec_static.qmax).astype(jnp.int8)
-        else:
-            am = jnp.max(jnp.abs(w))
-            s_w = jnp.where(am > 0, am / 127.0, jnp.float32(1.0))
-            wq_ref[...] = jnp.clip(jnp.round(w / s_w), -127,
-                                   127).astype(jnp.int8)
-        sw_ref[0, 0] = s_w
+        _quantize_resident_w(w, wq_ref, sw_ref, w_spec_static)
 
     slot = db_step(k, n_k, dmas)
 
     go = (int8_dot(gbuf[slot], wq_ref[...], _GW_DIMS).astype(jnp.float32)
-          * (meta_ref[0] * sw_ref[0, 0]))
+          * (meta_ref[0] * sw_ref[...]))
     go = go * act_deriv(zbuf[slot].astype(jnp.float32), act)
     go_ref[...] = maybe_kq(go, g_bits)
 
@@ -229,6 +225,7 @@ def bp_fused_unit(g: jax.Array, w: jax.Array, x: jax.Array, z: jax.Array,
     go_spec = pl.BlockSpec((bt, din), lambda k: (k, 0))
     wo_spec = pl.BlockSpec((din, dout), lambda k: (0, 0))
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    smem_spec = pl.BlockSpec(memory_space=pltpu.SMEM)   # scalars read in-body
     out_shape = [jax.ShapeDtypeStruct((t, din), jnp.float32),
                  jax.ShapeDtypeStruct((din, dout), jnp.float32)]
     params = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
@@ -261,7 +258,7 @@ def bp_fused_unit(g: jax.Array, w: jax.Array, x: jax.Array, z: jax.Array,
                                   w_out_bits=w_out_bits, act=act,
                                   w_spec_static=spec),
                 grid=grid,
-                in_specs=[any_spec, w_spec, any_spec, any_spec, any_spec],
+                in_specs=[any_spec, w_spec, any_spec, any_spec, smem_spec],
                 out_specs=[go_spec, wo_spec],
                 out_shape=out_shape,
                 scratch_shapes=db_scratch
@@ -275,7 +272,7 @@ def bp_fused_unit(g: jax.Array, w: jax.Array, x: jax.Array, z: jax.Array,
                               w_bits=w_bits, w_out_bits=w_out_bits, act=act,
                               w_spec_static=spec),
             grid=grid,
-            in_specs=[g_spec, w_spec, x_spec, z_spec, any_spec],
+            in_specs=[g_spec, w_spec, x_spec, z_spec, smem_spec],
             out_specs=[go_spec, wo_spec],
             out_shape=out_shape,
             scratch_shapes=[pltpu.VMEM((din, dout), jnp.int32),
@@ -291,7 +288,7 @@ def bp_fused_unit(g: jax.Array, w: jax.Array, x: jax.Array, z: jax.Array,
             functools.partial(_kernel_db, n_k=n_k, bt=bt, g_bits=g_bits,
                               w_bits=w_bits, w_out_bits=w_out_bits, act=act),
             grid=grid,
-            in_specs=[any_spec, w_spec, any_spec, any_spec, any_spec],
+            in_specs=[any_spec, w_spec, any_spec, any_spec, smem_spec],
             out_specs=[go_spec, wo_spec],
             out_shape=out_shape,
             scratch_shapes=db_scratch
@@ -303,7 +300,7 @@ def bp_fused_unit(g: jax.Array, w: jax.Array, x: jax.Array, z: jax.Array,
         functools.partial(_kernel, n_k=n_k, g_bits=g_bits, w_bits=w_bits,
                           w_out_bits=w_out_bits, act=act),
         grid=grid,
-        in_specs=[g_spec, w_spec, x_spec, z_spec, any_spec],
+        in_specs=[g_spec, w_spec, x_spec, z_spec, smem_spec],
         out_specs=[go_spec, wo_spec],
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((din, dout), jnp.float32),

@@ -163,6 +163,7 @@ def bp_gstep(g: jax.Array, w: jax.Array, z: Optional[jax.Array], *,
     z_spec = pl.BlockSpec((bm, bn), lambda i, j, k: (i, j))       # Z
     o_spec = pl.BlockSpec((bm, bn), lambda i, j, k: (i, j))
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    smem_spec = pl.BlockSpec(memory_space=pltpu.SMEM)   # scalars read in-body
     params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
     out_shape = jax.ShapeDtypeStruct((t, din), jnp.float32)
@@ -184,7 +185,7 @@ def bp_gstep(g: jax.Array, w: jax.Array, z: Optional[jax.Array], *,
             if z is not None:
                 in_specs.append(z_spec)
                 args.append(z)
-            in_specs.append(any_spec)
+            in_specs.append(smem_spec)
             args.append(meta)
 
             def kern_db8(*refs):
@@ -209,7 +210,7 @@ def bp_gstep(g: jax.Array, w: jax.Array, z: Optional[jax.Array], *,
         if z is not None:
             in_specs.append(z_spec)
             args.append(z)
-        in_specs.append(any_spec)
+        in_specs.append(smem_spec)
         args.append(meta)
 
         def kern(*refs):

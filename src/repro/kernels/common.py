@@ -60,10 +60,14 @@ def int8_dot(a, b, dims=None):
     ``dims`` follows ``lax.dot_general`` dimension_numbers; default is a
     plain [M,K]x[K,N] matmul.  Accumulation is exact int32 (the paper's
     wide accumulator registers — no rounding until the final rescale).
+    The precision is pinned to DEFAULT: integer MACs are exact at any
+    precision, and Mosaic refuses an int8 matmul carrying the fp32 contract
+    precision that ``jax_default_matmul_precision="highest"`` would stamp.
     """
-    if dims is None:
-        return jnp.dot(a, b, preferred_element_type=jnp.int32)
-    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.int32)
+    dims = dims or (((a.ndim - 1,), (0,)), ((), ()))
+    return jax.lax.dot_general(a, b, dims,
+                               precision=jax.lax.Precision.DEFAULT,
+                               preferred_element_type=jnp.int32)
 
 
 _GELU_C = 0.7978845608028654  # sqrt(2/pi)

@@ -165,6 +165,7 @@ def fxp_matmul(x: jax.Array, w: jax.Array, *,
     w_spec = pl.BlockSpec((bk, bn), lambda i, j, k: (k, j))
     o_spec = pl.BlockSpec((bm, bn), lambda i, j, k: (i, j))
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    smem_spec = pl.BlockSpec(memory_space=pltpu.SMEM)   # scalars read in-body
     params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
     out_shape = jax.ShapeDtypeStruct((m, n), jnp.float32)
@@ -178,7 +179,7 @@ def fxp_matmul(x: jax.Array, w: jax.Array, *,
                 functools.partial(_kernel_db_int8, n_k=n_k, bm=bm, bn=bn,
                                   bk=bk, out_bits=out_bits, act=act),
                 grid=grid,
-                in_specs=[any_spec, any_spec, any_spec],
+                in_specs=[any_spec, any_spec, smem_spec],
                 out_specs=o_spec,
                 out_shape=out_shape,
                 scratch_shapes=[pltpu.VMEM((2, bm, bk), jnp.int8),
@@ -192,7 +193,7 @@ def fxp_matmul(x: jax.Array, w: jax.Array, *,
             functools.partial(_kernel_int8, n_k=n_k, out_bits=out_bits,
                               act=act),
             grid=grid,
-            in_specs=[x_spec, w_spec, any_spec],
+            in_specs=[x_spec, w_spec, smem_spec],
             out_specs=o_spec,
             out_shape=out_shape,
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],

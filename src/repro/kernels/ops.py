@@ -38,6 +38,7 @@ fetch, not a wrapper path.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import contextvars
 import functools
@@ -59,6 +60,42 @@ from repro.quant.int8 import quantize_int8_absmax, quantize_int8_auto
 
 def _on_cpu() -> bool:
     return jax.default_backend() == "cpu"
+
+
+# ---------------------------------------------------------------------------
+# Kernel path counters: which path every kernel call site was traced onto
+# ---------------------------------------------------------------------------
+#
+# (kernel, path) -> number of traces.  Counted at trace time, so a jitted
+# step counts once per compile, not once per call.  "compiled" is the
+# Mosaic kernel; every other path is a fallback: "interpret" (no TPU: the
+# kernel ran in the Pallas interpreter), "no_tile" (no aligned block
+# divides a dim: jnp reference), "sharded" (traced under a multi-device
+# mesh: jnp reference, which XLA partitions) and "over_vmem" (the resident
+# frame does not fit the VMEM budget: jnp reference).  Drivers print them
+# at the end of a run.
+
+KERNEL_TRACES: collections.Counter = collections.Counter()
+
+
+def note_path(kernel: str, path: str) -> None:
+    KERNEL_TRACES[(kernel, path)] += 1
+
+
+def interpret_mode(kernel: str) -> bool:
+    """Whether ``kernel`` runs in the Pallas interpreter (anything but a
+    TPU backend); the trace is counted either way."""
+    interpret = jax.default_backend() != "tpu"
+    note_path(kernel, "interpret" if interpret else "compiled")
+    return interpret
+
+
+def format_kernel_traces() -> str:
+    """``kernel/path=n`` for each path counted so far, or "none"."""
+    if not KERNEL_TRACES:
+        return "none"
+    return ", ".join(f"{k}/{p}={n}"
+                     for (k, p), n in sorted(KERNEL_TRACES.items()))
 
 
 # ---------------------------------------------------------------------------
@@ -107,6 +144,15 @@ def resolve_double_buffer(double_buffer: Optional[bool] = None) -> bool:
     if double_buffer is None:
         return not _on_cpu()
     return bool(double_buffer)
+
+
+def _dma_blocks(double_buffer, sublanes=(), lanes=()) -> bool:
+    """``resolve_double_buffer`` for one call.  The explicit DMA path
+    slices HBM in whole (32, 128) tiles (int8 sublanes pack 32 rows), so
+    blocks off that grid keep the implicit pipeline — same numerics."""
+    return (resolve_double_buffer(double_buffer)
+            and all(d % 32 == 0 for d in sublanes)
+            and all(d % 128 == 0 for d in lanes))
 
 
 # ---------------------------------------------------------------------------
@@ -172,6 +218,18 @@ def _candidates(dim: int) -> list:
     return [b for b in range(start, 7, -8) if dim % b == 0]
 
 
+def _grid_vmem(bm: int, bn: int, bk: int, itemsize: int, acc_itemsize: int,
+               slots: int) -> int:
+    """VMEM bytes one grid step of a [bm,bk]x[bk,bn] kernel holds: the
+    streamed input blocks (``slots`` deep); the f32 output block and one
+    f32 [bm, bn] side input (bp_gstep's Z, sgd_dw_update's W), each
+    double-buffered by the pipeline; the accumulator; and the body's f32
+    temporaries (the cast operand blocks and the dot result)."""
+    return (slots * (bm * bk + bk * bn) * itemsize
+            + (bm * bk + bk * bn) * 4
+            + bm * bn * (2 * 4 + 2 * 4 + acc_itemsize + 4))
+
+
 def tune_blocks(m: int, n: int, k: int, itemsize: int = 4,
                 acc_itemsize: int = 4,
                 double_buffer: bool = True) -> Optional[tuple]:
@@ -201,9 +259,7 @@ def tune_blocks(m: int, n: int, k: int, itemsize: int = 4,
     for bm in cm:
         for bn in cn:
             for bk in ck:
-                # slotted input blocks + resident output + accumulator
-                vmem = (slots * (bm * bk + bk * bn) * itemsize
-                        + bm * bn * (4 + acc_itemsize))
+                vmem = _grid_vmem(bm, bn, bk, itemsize, acc_itemsize, slots)
                 if vmem > VMEM_BUDGET_BYTES:
                     continue
                 mxu = sum(b % 128 == 0 or b == full
@@ -383,16 +439,20 @@ def prime_tune_cache(shapes: dict) -> dict:
 def train_tune_shapes(cfg, global_batch: int, seq_len: int) -> dict:
     """The ``prime_tune_cache`` shape set a train run's hot matmuls hit:
     MLP up/down, QKV/output projections and the fused TDM frame at
-    t = batch * seq tokens, on both datapaths (f32 and int8 payloads)."""
+    t = batch * seq tokens, on both datapaths (f32 and int8 payloads).
+    Each [K, N] weight gives the dense unit's three grids: x @ w and
+    dz @ w^T over (t, ., .), and the dW grid (K, N, t)."""
     t = int(global_batch) * int(seq_len)
     d = int(cfg.d_model)
     ff = int(cfg.d_ff or cfg.moe_d_ff or 0)
-    pairs = []
+    weights = []
     if ff:
-        pairs += [(t, ff, d), (t, d, ff)]
+        weights += [(d, ff), (ff, d)]
     if cfg.num_heads:
         hw = int((cfg.padded_heads or cfg.num_heads) * cfg.head_dim)
-        pairs += [(t, hw, d), (t, d, hw)]
+        weights += [(d, hw), (hw, d)]
+    pairs = [(t, n, k) for (k, n) in weights]
+    pairs += [(k, n, t) for (k, n) in weights]
     shapes = {"blocks": [], "fused": []}
     for (m, n, k) in pairs:
         for item in (1, 4):
@@ -424,6 +484,20 @@ def serve_tune_shapes(cfg, *, num_blocks: int, block_size: int,
 # Jit'd wrappers (ref fallback on untileable shapes)
 # ---------------------------------------------------------------------------
 
+def _tiles(kernel: str, m: int, n: int, k: int, itemsize: int):
+    """``tune_blocks`` for a wrapper, or None (counted) where the call
+    takes the jnp reference: a shape with no tile ("no_tile"), or a trace
+    under a mesh of more than one device ("sharded": Mosaic kernels are
+    not partitioned automatically, XLA's dot is)."""
+    if jax.sharding.get_abstract_mesh().size > 1:
+        note_path(kernel, "sharded")
+        return None
+    blocks = tune_blocks(m, n, k, itemsize=itemsize)
+    if blocks is None:
+        note_path(kernel, "no_tile")
+    return blocks
+
+
 @functools.partial(jax.jit, static_argnames=(
     "xa_bits", "w_bits", "out_bits", "act", "datapath", "double_buffer"))
 def fxp_matmul_op(x, w, *, xa_bits=(4, 10), w_bits=(2, 12),
@@ -431,8 +505,10 @@ def fxp_matmul_op(x, w, *, xa_bits=(4, 10), w_bits=(2, 12),
                   double_buffer=None):
     m, k = x.shape
     n = w.shape[1]
-    db = resolve_double_buffer(double_buffer)
-    blocks = tune_blocks(m, n, k, itemsize=1 if datapath == "int8" else 4)
+    blocks = _tiles("fxp_matmul", m, n, k, 1 if datapath == "int8" else 4)
+    if blocks is not None:
+        bm, bn, bk = blocks
+        db = _dma_blocks(double_buffer, (bm, bk), (bk, bn))
     if datapath == "int8":
         if blocks is None:
             return ref.fxp_matmul_int8_ref(x, w, xa_bits=xa_bits,
@@ -440,18 +516,18 @@ def fxp_matmul_op(x, w, *, xa_bits=(4, 10), w_bits=(2, 12),
                                            act=act)
         qx, sx = quantize_int8_auto(x, xa_bits)
         qw, sw = quantize_int8_auto(w, w_bits)
-        bm, bn, bk = blocks
         return fxp_matmul(qx, qw, out_bits=out_bits, act=act,
                           bm=bm, bn=bn, bk=bk, datapath="int8",
-                          scale=sx * sw, interpret=_on_cpu(),
+                          scale=sx * sw,
+                          interpret=interpret_mode("fxp_matmul"),
                           double_buffer=db)
     if blocks is None:
         return ref.fxp_matmul_ref(x, w, xa_bits=xa_bits, w_bits=w_bits,
                                   out_bits=out_bits, act=act)
-    bm, bn, bk = blocks
     return fxp_matmul(x, w, xa_bits=xa_bits, w_bits=w_bits,
                       out_bits=out_bits, act=act,
-                      bm=bm, bn=bn, bk=bk, interpret=_on_cpu(),
+                      bm=bm, bn=bn, bk=bk,
+                      interpret=interpret_mode("fxp_matmul"),
                       double_buffer=db)
 
 
@@ -461,24 +537,24 @@ def bp_gstep_op(g, w, z, *, g_bits=(2, 12), act="relu", datapath="emulate",
                 g_in_bits=(2, 12), w_bits=(2, 12), double_buffer=None):
     t, dout = g.shape
     din = w.shape[0]
-    db = resolve_double_buffer(double_buffer)
-    blocks = tune_blocks(t, din, dout, itemsize=1 if datapath == "int8" else 4)
+    blocks = _tiles("bp_gstep", t, din, dout, 1 if datapath == "int8" else 4)
+    if blocks is not None:
+        bm, bn, bk = blocks
+        db = _dma_blocks(double_buffer, (bm, bn), (bk,))
     if datapath == "int8":
         if blocks is None:
             return ref.bp_gstep_int8_ref(g, w, z, g_in_bits=g_in_bits,
                                          w_bits=w_bits, g_bits=g_bits, act=act)
         qg, sg = quantize_int8_auto(g, g_in_bits)
         qw, sw = quantize_int8_auto(w, w_bits)
-        bm, bn, bk = blocks
         return bp_gstep(qg, qw, z, g_bits=g_bits, act=act,
                         bm=bm, bn=bn, bk=bk, datapath="int8",
-                        scale=sg * sw, interpret=_on_cpu(),
+                        scale=sg * sw, interpret=interpret_mode("bp_gstep"),
                         double_buffer=db)
     if blocks is None:
         return ref.bp_gstep_ref(g, w, z, g_bits=g_bits, act=act)
-    bm, bn, bk = blocks
     return bp_gstep(g, w, z, g_bits=g_bits, act=act,
-                    bm=bm, bn=bn, bk=bk, interpret=_on_cpu(),
+                    bm=bm, bn=bn, bk=bk, interpret=interpret_mode("bp_gstep"),
                     double_buffer=db)
 
 
@@ -488,7 +564,8 @@ def sgd_dw_update_op(x, g, w, lr, *, w_bits=None, datapath="emulate",
                      xa_bits=(4, 10), g_in_bits=(2, 12)):
     t, din = x.shape
     dout = g.shape[1]
-    blocks = tune_blocks(din, dout, t, itemsize=1 if datapath == "int8" else 4)
+    blocks = _tiles("sgd_dw_update", din, dout, t,
+                    1 if datapath == "int8" else 4)
     if datapath == "int8":
         if blocks is None:
             return ref.sgd_dw_update_int8_ref(x, g, w, lr, xa_bits=xa_bits,
@@ -499,12 +576,14 @@ def sgd_dw_update_op(x, g, w, lr, *, w_bits=None, datapath="emulate",
         bm, bn, bk = blocks
         return sgd_dw_update(qx, qg, w, lr, w_bits=w_bits,
                              bm=bm, bn=bn, bk=bk, datapath="int8",
-                             scale=sx * sg, interpret=_on_cpu())
+                             scale=sx * sg,
+                             interpret=interpret_mode("sgd_dw_update"))
     if blocks is None:
         return ref.sgd_dw_update_ref(x, g, w, lr, w_bits=w_bits)
     bm, bn, bk = blocks
     return sgd_dw_update(x, g, w, lr, w_bits=w_bits,
-                         bm=bm, bn=bn, bk=bk, interpret=_on_cpu())
+                         bm=bm, bn=bn, bk=bk,
+                         interpret=interpret_mode("sgd_dw_update"))
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -517,8 +596,11 @@ def bp_fused_unit_op(g, w, x, z, lr, *, g_bits=(2, 12), w_bits=(2, 12),
     oracle when the frame cannot be tiled/fit."""
     t, dout = g.shape
     din = w.shape[0]
-    db = resolve_double_buffer(double_buffer)
     bt = tune_fused(t, din, dout, itemsize=1 if datapath == "int8" else 4)
+    if bt is None:
+        note_path("bp_fused_unit", "over_vmem")
+    else:
+        db = _dma_blocks(double_buffer, (bt,))
     if datapath == "int8":
         if bt is None:
             return ref.bp_fused_unit_int8_ref(
@@ -529,14 +611,16 @@ def bp_fused_unit_op(g, w, x, z, lr, *, g_bits=(2, 12), w_bits=(2, 12),
         return bp_fused_unit(qg, w, qx, z, lr, g_bits=g_bits, w_bits=w_bits,
                              w_out_bits=w_out_bits, act=act, bt=bt,
                              datapath="int8", g_scale=sg, x_scale=sx,
-                             interpret=_on_cpu(), double_buffer=db)
+                             interpret=interpret_mode("bp_fused_unit"),
+                             double_buffer=db)
     if bt is None:
         return ref.bp_fused_unit_ref(g, w, x, z, lr, g_bits=g_bits,
                                      w_bits=w_bits, w_out_bits=w_out_bits,
                                      act=act)
     return bp_fused_unit(g, w, x, z, lr, g_bits=g_bits, w_bits=w_bits,
                          w_out_bits=w_out_bits, act=act, bt=bt,
-                         interpret=_on_cpu(), double_buffer=db)
+                         interpret=interpret_mode("bp_fused_unit"),
+                         double_buffer=db)
 
 
 # ---------------------------------------------------------------------------
@@ -555,15 +639,15 @@ def dense_fwd(x2, w, backend: str):
     if backend == "int8":
         qx, sx = quantize_int8_absmax(x2)
         qw, sw = quantize_int8_absmax(w)
-        blocks = tune_blocks(m, n, k, itemsize=1)
+        blocks = _tiles("dense_fwd", m, n, k, 1)
         if blocks is None:
             return int8_dot(qx, qw).astype(jnp.float32) * (sx * sw)
         bm, bn, bk = blocks
         return fxp_matmul(qx, qw, out_bits=None, act="identity",
                           bm=bm, bn=bn, bk=bk, datapath="int8",
-                          scale=sx * sw, interpret=_on_cpu(),
-                          double_buffer=resolve_double_buffer())
-    blocks = tune_blocks(m, n, k)
+                          scale=sx * sw, interpret=interpret_mode("dense_fwd"),
+                          double_buffer=_dma_blocks(None, (bm, bk), (bk, bn)))
+    blocks = _tiles("dense_fwd", m, n, k, 4)
     if blocks is None:
         return jnp.dot(x2.astype(jnp.float32), w.astype(jnp.float32),
                        preferred_element_type=jnp.float32)
@@ -571,8 +655,8 @@ def dense_fwd(x2, w, backend: str):
     return fxp_matmul(x2.astype(jnp.float32), w.astype(jnp.float32),
                       xa_bits=None, w_bits=None, out_bits=None,
                       act="identity", bm=bm, bn=bn, bk=bk,
-                      interpret=_on_cpu(),
-                      double_buffer=resolve_double_buffer())
+                      interpret=interpret_mode("dense_fwd"),
+                      double_buffer=_dma_blocks(None, (bm, bk), (bk, bn)))
 
 
 def dense_bwd_dx(dz, w, backend: str):
@@ -584,23 +668,23 @@ def dense_bwd_dx(dz, w, backend: str):
     if backend == "int8":
         qg, sg = quantize_int8_absmax(dz)
         qw, sw = quantize_int8_absmax(w)
-        blocks = tune_blocks(m, k, n, itemsize=1)
+        blocks = _tiles("dense_bwd_dx", m, k, n, 1)
         if blocks is None:
             return int8_dot(qg, qw.T).astype(jnp.float32) * (sg * sw)
         bm, bn, bk = blocks
         return bp_gstep(qg, qw, None, g_bits=None, act="identity",
                         bm=bm, bn=bn, bk=bk, datapath="int8",
-                        scale=sg * sw, interpret=_on_cpu(),
-                        double_buffer=resolve_double_buffer())
-    blocks = tune_blocks(m, k, n)
+                        scale=sg * sw, interpret=interpret_mode("dense_bwd_dx"),
+                        double_buffer=_dma_blocks(None, (bm, bn), (bk,)))
+    blocks = _tiles("dense_bwd_dx", m, k, n, 4)
     if blocks is None:
         return jnp.dot(dz, w.astype(jnp.float32).T,
                        preferred_element_type=jnp.float32)
     bm, bn, bk = blocks
     return bp_gstep(dz, w.astype(jnp.float32), None, g_bits=None,
                     act="identity", bm=bm, bn=bn, bk=bk,
-                    interpret=_on_cpu(),
-                    double_buffer=resolve_double_buffer())
+                    interpret=interpret_mode("dense_bwd_dx"),
+                    double_buffer=_dma_blocks(None, (bm, bn), (bk,)))
 
 
 def dense_bwd_dw(x2, dz, backend: str):
@@ -610,17 +694,18 @@ def dense_bwd_dw(x2, dz, backend: str):
     if backend == "int8":
         qx, sx = quantize_int8_absmax(x2)
         qg, sg = quantize_int8_absmax(dz)
-        blocks = tune_blocks(k, n, m, itemsize=1)
+        blocks = _tiles("dense_bwd_dw", k, n, m, 1)
         if blocks is None:
             return int8_dot(qx.T, qg).astype(jnp.float32) * (sx * sg)
         bm, bn, bk = blocks
         return sgd_dw_update(qx, qg, None, 0.0, bm=bm, bn=bn, bk=bk,
                              datapath="int8", scale=sx * sg,
-                             interpret=_on_cpu())
-    blocks = tune_blocks(k, n, m)
+                             interpret=interpret_mode("dense_bwd_dw"))
+    blocks = _tiles("dense_bwd_dw", k, n, m, 4)
     if blocks is None:
         return jnp.dot(x2.astype(jnp.float32).T, dz,
                        preferred_element_type=jnp.float32)
     bm, bn, bk = blocks
     return sgd_dw_update(x2.astype(jnp.float32), dz, None, 0.0,
-                         bm=bm, bn=bn, bk=bk, interpret=_on_cpu())
+                         bm=bm, bn=bn, bk=bk,
+                         interpret=interpret_mode("dense_bwd_dw"))

@@ -48,15 +48,18 @@ def _attend(q, kk, vv, length, t, scale):
 
     Op-for-op the batched math of ``serving.engine._paged_attention`` with
     B=1, C=1 — the bitwise contract between kernel and ref lives here.
+    The size-1 batch axis is left out of the contractions: Mosaic's
+    matmul takes one batch dim (the heads).
     """
-    s = jnp.einsum("bqhd,bkhd->bhqk", q[None], kk[None],
+    s = jnp.einsum("qhd,khd->hqk", q, kk,
                    preferred_element_type=jnp.float32) * scale
     kpos = jax.lax.broadcasted_iota(jnp.int32, (1, 1, t), 2)
     ok = kpos <= length
-    s = s + jnp.where(ok, 0.0, NEG_INF)[:, None]
+    s = s + jnp.where(ok, 0.0, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bhqk,bkhd->bqhd", p, vv[None])
-    return out[0, 0]  # [H, hd]
+    out = jnp.einsum("hqk,khd->qhd", p, vv,
+                     preferred_element_type=jnp.float32).astype(q.dtype)
+    return out[0]  # [H, hd]
 
 
 def _kernel(tbl_ref, len_ref, q_ref, kp_ref, vp_ref, o_ref, kg_ref, vg_ref,
@@ -84,10 +87,13 @@ def _kernel_int8(tbl_ref, len_ref, q_ref, kp_ref, vp_ref, ks_ref, vs_ref,
         bid = tbl_ref[b, i]
         kb = kp_ref[pl.ds(bid, 1)][0]
         vb = vp_ref[pl.ds(bid, 1)][0]
-        ks = ks_ref[pl.ds(bid, 1)][0]
-        vs = vs_ref[pl.ds(bid, 1)][0]
-        kg_ref[pl.ds(i * bs, bs)] = kb.astype(dt) * ks[:, None, None].astype(dt)
-        vg_ref[pl.ds(i * bs, bs)] = vb.astype(dt) * vs[:, None, None].astype(dt)
+        # [block, 1, 1] -> [block, 1, hd]: Mosaic broadcasts lanes and
+        # sublanes in separate steps
+        lanes = (bs, 1, kb.shape[-1])
+        ks = jnp.broadcast_to(ks_ref[pl.ds(bid, 1)][0], lanes)
+        vs = jnp.broadcast_to(vs_ref[pl.ds(bid, 1)][0], lanes)
+        kg_ref[pl.ds(i * bs, bs)] = kb.astype(dt) * ks.astype(dt)
+        vg_ref[pl.ds(i * bs, bs)] = vb.astype(dt) * vs.astype(dt)
     kk = _expand_heads(kg_ref[...], groups)
     vv = _expand_heads(vg_ref[...], groups)
     o_ref[...] = _attend(q_ref[...][0][None], kk, vv, len_ref[b],
@@ -119,7 +125,8 @@ def _ref(q, pool_l, tables, lens, groups: int, scale: float):
     ok = kpos[None, None, :] <= lens[:, None, None]
     s = s + jnp.where(ok, 0.0, NEG_INF)[:, None]
     p = jax.nn.softmax(s, axis=-1).astype(dt)
-    out = jnp.einsum("bhqk,bkhd->bqhd", p, vv)
+    out = jnp.einsum("bhqk,bkhd->bqhd", p, vv,
+                     preferred_element_type=jnp.float32).astype(dt)
     return out[:, 0]
 
 
@@ -129,7 +136,7 @@ def _call_kernel(q, pool_l, tables, lens, groups: int, scale: float):
     n, bs, hkv, _ = pool_l["k"].shape
     int8 = "k_scale" in pool_l
     t = m * bs
-    interpret = kops._on_cpu()
+    interpret = kops.interpret_mode("paged_attention")
 
     def full(x):
         nd = x.ndim
@@ -141,8 +148,12 @@ def _call_kernel(q, pool_l, tables, lens, groups: int, scale: float):
     if int8:
         body = functools.partial(_kernel_int8, m=m, bs=bs, groups=groups,
                                  scale=scale)
-        in_specs += [full(pool_l["k_scale"]), full(pool_l["v_scale"])]
-        args += [pool_l["k_scale"], pool_l["v_scale"]]
+        # per-token scales as [N, block, 1, 1]: a block's [block, 1, 1]
+        # slice broadcasts over (heads, hd) without an in-kernel reshape
+        scales = [pool_l[k].reshape(n, bs, 1, 1)
+                  for k in ("k_scale", "v_scale")]
+        in_specs += [full(x) for x in scales]
+        args += scales
     else:
         body = functools.partial(_kernel, m=m, bs=bs, groups=groups,
                                  scale=scale)
@@ -178,5 +189,6 @@ def paged_attention(q, pool_l: dict, tables, lens, *, groups: int,
     fits = kops.tune_paged(n, bs, m, hkv, hd, groups,
                            itemsize=pool_l["k"].dtype.itemsize)
     if fits is None:
+        kops.note_path("paged_attention", "over_vmem")
         return _ref(q, pool_l, tables, lens, groups, scale)
     return _call_kernel(q, pool_l, tables, lens, groups, scale)

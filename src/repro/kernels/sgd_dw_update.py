@@ -102,7 +102,7 @@ def sgd_dw_update(x: jax.Array, g: jax.Array, w: Optional[jax.Array], lr,
     g_spec = pl.BlockSpec((bk, bn), lambda i, j, k: (k, j))   # G
     w_spec = pl.BlockSpec((bm, bn), lambda i, j, k: (i, j))   # W
     o_spec = pl.BlockSpec((bm, bn), lambda i, j, k: (i, j))
-    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    smem_spec = pl.BlockSpec(memory_space=pltpu.SMEM)   # scalars read in-body
     params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
     out_shape = jax.ShapeDtypeStruct((din, dout), jnp.float32)
@@ -117,7 +117,7 @@ def sgd_dw_update(x: jax.Array, g: jax.Array, w: Optional[jax.Array], lr,
         if w is not None:
             in_specs.append(w_spec)
             args.append(w)
-        in_specs.append(any_spec)
+        in_specs.append(smem_spec)
         args.append(meta)
 
         def kern(*refs):
@@ -142,7 +142,7 @@ def sgd_dw_update(x: jax.Array, g: jax.Array, w: Optional[jax.Array], lr,
     if w is not None:
         in_specs.append(w_spec)
         args.append(w)
-    in_specs.append(any_spec)
+    in_specs.append(smem_spec)
     args.append(lr_arr)
 
     def kern(*refs):

@@ -18,16 +18,20 @@ import jax
 import numpy as np
 
 from repro.configs import ARCH_NAMES, get_config
+from repro.kernels import ops as kops
 from repro.launch.train import _reduce
 from repro.models import lm
 from repro.serving import (BatchScheduler, EngineHooks, Request, ServeConfig,
                            paged_supported)
+from repro.util.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_NAMES, default="qwen1.5-0.5b")
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the weights and the request prompts")
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=12)
@@ -49,17 +53,18 @@ def main(argv=None):
     ap.add_argument("--kernel-backend", default=None,
                     choices=["auto", "off", "emulate", "int8"],
                     help="decode-hook kernel backend: non-off enables the "
-                         "fused decode-prologue kernel (default: unset, "
-                         "unfused decode)")
+                         "fused decode-prologue and paged-attention kernels "
+                         "(default: unset, unfused decode)")
     ap.add_argument("--profile", type=int, default=0, metavar="N",
                     help="capture a jax.profiler trace of the first N "
                          "scheduler ticks (trace directory printed at exit)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = _reduce(cfg)
-    params = lm.init_params(jax.random.key(0), cfg)
+    params = lm.init_params(jax.random.key(args.seed), cfg)
 
     mode = args.mode
     if mode == "auto":
@@ -71,7 +76,9 @@ def main(argv=None):
                         block_size=args.block_size,
                         prefill_chunk=args.prefill_chunk,
                         cache_dtype=cache_dtype,
-                        kernel_backend=args.kernel_backend)
+                        kernel_backend=args.kernel_backend,
+                        attn_impl=("kernel" if args.kernel_backend
+                                   not in (None, "off") else None))
     print(f"[serve] {cfg.name} ({cfg.family}) slots={args.slots} "
           f"mode={mode} cache={cache_dtype} "
           f"kernel_backend={args.kernel_backend or 'unset'}", flush=True)
@@ -91,7 +98,7 @@ def main(argv=None):
 
     sched = BatchScheduler(serve, EngineHooks.for_model(params, cfg, serve))
 
-    rng = np.random.default_rng(0)
+    rng = np.random.default_rng(args.seed)
     t0 = time.time()
     reqs = []
     for i in range(args.requests):
@@ -127,7 +134,8 @@ def main(argv=None):
     if trace_dir:
         print(f"[serve] profiler trace ({args.profile} tick(s)): {trace_dir}",
               flush=True)
-    return finished
+    print(f"[serve] kernel paths: {kops.format_kernel_traces()}", flush=True)
+    return {"finished": finished, "ticks": sched.steps_run, "seconds": dt}
 
 
 if __name__ == "__main__":

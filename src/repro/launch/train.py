@@ -37,16 +37,13 @@ from repro.core.steps import (apply_resume_extra, capture_resume_extra,
 from repro.data import SyntheticLMDataset, StragglerTolerantLoader
 from repro.dist.api import activation_sharding_ctx, make_default_rules
 from repro.dist.pipeline import get_schedule
-from repro.dist.sharding import param_pspecs, to_named
+from repro.dist.sharding import batch_pspecs, opt_pspecs, param_pspecs, to_named
 from repro.ft import FaultPlan
+from repro.kernels import ops as kops
 from repro.launch.mesh import batch_axes, make_debug_mesh, pipe_axis_size
 from repro.models import lm
 from repro.optim import Hyper, OptimizerConfig, cosine_schedule
-
-
-def reduced_for_cpu(cfg):
-    from test_support_reduce import reduce_config  # pragma: no cover
-    return reduce_config(cfg)
+from repro.util.compile_cache import enable_compile_cache
 
 
 def _reduce(cfg):
@@ -78,6 +75,8 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_NAMES, default="qwen1.5-0.5b")
     ap.add_argument("--steps", type=int, default=100)
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the initial weights and the synthetic data")
     ap.add_argument("--seq-len", type=int, default=128)
     ap.add_argument("--global-batch", type=int, default=8)
     ap.add_argument("--lr", type=float, default=3e-3)
@@ -179,6 +178,7 @@ def main(argv=None):
                     help="capture a jax.profiler trace of the first N steps "
                          "(trace directory printed at exit)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -245,8 +245,19 @@ def main(argv=None):
     sched = cosine_schedule(args.lr, warmup=max(10, args.steps // 20),
                             total=args.steps)
 
-    params = lm.init_params(jax.random.key(0), cfg)
-    opt_state = init_train_state(params, ocfg)
+    # params, optimizer state and (below) every batch are placed on the
+    # mesh by the sharding rules, on fresh runs and restores alike; the
+    # state is initialized in one jitted call straight into that placement
+    def init_state(key):
+        params = lm.init_params(key, cfg)
+        return params, init_train_state(params, ocfg)
+
+    key = jax.random.key(args.seed)
+    p_shapes, o_shapes = jax.eval_shape(init_state, key)
+    p_specs = param_pspecs(cfg, p_shapes, mesh)
+    state_sh = (to_named(p_specs, mesh),
+                to_named(opt_pspecs(cfg, o_shapes, p_specs, mesh), mesh))
+    params, opt_state = jax.jit(init_state, out_shardings=state_sh)(key)
     start_step = 0
 
     plan = FaultPlan.from_env(args.fault_plan)
@@ -257,11 +268,9 @@ def main(argv=None):
     # carries the killed run's measured transport decisions, and installing
     # them first keeps the resumed collective schedule (and its numerics)
     # identical instead of re-measuring on a possibly noisier machine
-    p_sh = to_named(param_pspecs(cfg, params, mesh), mesh)
     if args.resume and args.ckpt_dir and latest_step(args.ckpt_dir) is not None:
         (params, opt_state), ckpt_step, extra = restore_checkpoint(
-            args.ckpt_dir, (params, opt_state),
-            shardings=(p_sh, None) if args.model > 1 else None)
+            args.ckpt_dir, (params, opt_state), shardings=state_sh)
         start_step = apply_resume_extra(extra, cfg, ckpt_step,
                                         anneal=args.bit_anneal)
         print(f"[train] resumed from step {start_step}", flush=True)
@@ -296,7 +305,8 @@ def main(argv=None):
                               fault=plan.ckpt_fault if plan else None)
             if args.ckpt_dir else None)
 
-    ds = SyntheticLMDataset(cfg.vocab_size, args.seq_len, args.global_batch)
+    ds = SyntheticLMDataset(cfg.vocab_size, args.seq_len, args.global_batch,
+                            seed=args.seed)
     fetch = plan.wrap_fetch(ds.batch_at) if plan else ds.batch_at
     loader = StragglerTolerantLoader(fetch, deadline_s=args.deadline_s,
                                      start_step=start_step)
@@ -326,7 +336,7 @@ def main(argv=None):
             ckpt.wait()
             plan.corrupt_checkpoint(args.ckpt_dir, next_step)
 
-    losses = []
+    losses, step_seconds = [], []
     trace_dir, tracing = None, False
     if args.profile > 0:
         trace_dir = tempfile.mkdtemp(prefix="repro-trace-train-")
@@ -339,8 +349,8 @@ def main(argv=None):
                     tracing = True
                 if plan is not None:
                     plan.check_crash(step)
-                batch = {k: jnp.asarray(v)
-                         for k, v in loader.get(step).items()}
+                t_step = time.time()
+                batch = dict(loader.get(step))
                 # the synthetic LM loader only makes tokens/labels; encdec
                 # and vlm need their modality-side inputs too (deterministic
                 # per step, so checkpoint-resume replays the same stream)
@@ -353,6 +363,8 @@ def main(argv=None):
                     batch["patch_embeds"] = jax.random.normal(
                         jax.random.fold_in(jax.random.key(3), step),
                         (bsz, cfg.num_patches, cfg.d_model), jnp.float32)
+                batch = jax.device_put(
+                    batch, to_named(batch_pspecs(batch, mesh), mesh))
                 hyper = Hyper(lr=jnp.float32(sched(step)),
                               step=jnp.int32(step))
                 rng = (jax.random.fold_in(jax.random.key(1), step)
@@ -360,6 +372,7 @@ def main(argv=None):
                 params, opt_state, metrics = step_fn(params, opt_state, batch,
                                                      hyper, bits, rng)
                 losses.append(float(metrics["loss"]))
+                step_seconds.append(time.time() - t_step)
                 if tracing and step - start_step + 1 >= args.profile:
                     jax.profiler.stop_trace()
                     tracing = False
@@ -393,7 +406,8 @@ def main(argv=None):
     print(f"[train] done: loss {losses[0]:.4f} -> {losses[-1]:.4f} "
           f"({np.mean(losses[:5]):.3f} -> {np.mean(losses[-5:]):.3f} smoothed)",
           flush=True)
-    return losses
+    print(f"[train] kernel paths: {kops.format_kernel_traces()}", flush=True)
+    return {"losses": losses, "step_seconds": step_seconds}
 
 
 if __name__ == "__main__":

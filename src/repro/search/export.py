@@ -149,7 +149,7 @@ def kv_reference(x):
 
 def export_prologue_weights(attn_params: dict):
     """The exported decode-prologue weight rule: per-tensor absmax int8 on
-    the 2D-reshaped QKV projections, scales stacked [1, 3] — exactly what
+    the 2D-reshaped QKV projections, scales stacked [3] — exactly what
     ``kernels.decode_prologue`` computes internally under the int8 backend.
 
     Returns ``(qwq, qwk, qwv, wscales)`` ready for ``DP._ref_int8``.
@@ -160,7 +160,7 @@ def export_prologue_weights(attn_params: dict):
     qwq, swq = quantize_int8_absmax(wq.reshape(d, h * hd))
     qwk, swk = quantize_int8_absmax(wk.reshape(d, hkv * hd))
     qwv, swv = quantize_int8_absmax(wv.reshape(d, hkv * hd))
-    return qwq, qwk, qwv, jnp.stack([swq, swk, swv]).reshape(1, 3)
+    return qwq, qwk, qwv, jnp.stack([swq, swk, swv])
 
 
 def serve_layer_quant(x, lq: LayerQuant):
@@ -243,19 +243,16 @@ def check_prologue_parity(key=None) -> dict:
     pos = jnp.array([0, 5, 17], jnp.int32)
 
     qwq, qwk, qwv, wscales = export_prologue_weights(attn)
-    stat = dict(use_rope=bool(cfg.use_rope), theta=float(cfg.rope_theta),
-                eps=float(cfg.norm_eps), h=h, hkv=hkv, hd=hd)
-    ref = jax.jit(lambda xx: DP._ref_int8(
+    ref = jax.jit(lambda xx: DP.rows_to_heads(*DP._ref_int8(
         xx[:, 0, :], norm["scale"].reshape(1, d), qwq, qwk, qwv, wscales,
-        None, pos, **stat))
+        None, eps=float(cfg.norm_eps)), cfg, pos))
     want = ref(x)
 
     with kops.kernel_backend_ctx("int8"):
         got = jax.jit(
             lambda xx: DP.decode_prologue(norm, attn, xx, cfg, pos))(x)
 
-    diffs = [float(jnp.max(jnp.abs(g[:, 0] - w)))
-             for g, w in zip(got, want)]
+    diffs = [float(jnp.max(jnp.abs(g - w))) for g, w in zip(got, want)]
     return {"prologue_max_diff": max(diffs), "ok": max(diffs) == 0.0}
 
 

@@ -160,7 +160,12 @@ class EngineHooks:
 
     @classmethod
     def for_model(cls, params, cfg, serve: ServeConfig) -> "EngineHooks":
-        """Build jitted closures over (params, cfg) for either mode.
+        """Build jitted hooks over (params, cfg) for either mode.
+
+        ``params`` reach each jitted function as an argument, bound with
+        ``partial``: closed over, they would be baked into the executable
+        as constants (gigabytes at published widths, beyond what the
+        persistent compilation cache can store).
 
         ``serve.kernel_backend`` installs a kernel backend around the
         DECODE hook only (trace- and call-time), turning on the fused
@@ -182,14 +187,14 @@ class EngineHooks:
         if serve.mode == "paged":
             pool = E.init_paged_state(cfg, serve.resolved_num_blocks,
                                       serve.block_size, dtype)
-            decode = _decode_backend(jax.jit(
-                lambda pool, tables, lens, toks: E.paged_decode_step(
-                    params, cfg, pool, tables, lens, toks, serve.attn_impl),
-                donate_argnums=(0,)))
-            chunk = jax.jit(
-                lambda pool, table, toks, start: E.paged_prefill_chunk(
-                    params, cfg, pool, table, toks, start),
-                donate_argnums=(0,))
+            decode = _decode_backend(partial(jax.jit(
+                lambda p, pool, tables, lens, toks: E.paged_decode_step(
+                    p, cfg, pool, tables, lens, toks, serve.attn_impl),
+                donate_argnums=(1,)), params))
+            chunk = partial(jax.jit(
+                lambda p, pool, table, toks, start: E.paged_prefill_chunk(
+                    p, cfg, pool, table, toks, start),
+                donate_argnums=(1,)), params)
             copy = jax.jit(
                 lambda pool, src, dst: {
                     k: x.at[:, dst].set(x[:, src]) for k, x in pool.items()},
@@ -199,14 +204,14 @@ class EngineHooks:
         state = E.init_decode_state(cfg, serve.num_slots, serve.max_len,
                                     dtype)
 
-        prefill_one = jax.jit(
-            lambda tokens: E.prefill(params, cfg,
-                                     {"tokens": jnp.asarray(tokens)},
-                                     serve.max_len, dtype))
+        prefill_one = partial(jax.jit(
+            lambda p, tokens: E.prefill(p, cfg,
+                                        {"tokens": jnp.asarray(tokens)},
+                                        serve.max_len, dtype)), params)
 
-        decode = _decode_backend(jax.jit(
-            lambda state, toks: E.decode_step(params, cfg, state, toks),
-            donate_argnums=(0,)))
+        decode = _decode_backend(partial(jax.jit(
+            lambda p, state, toks: E.decode_step(p, cfg, state, toks),
+            donate_argnums=(1,)), params))
 
         @partial(jax.jit, donate_argnums=(0,))
         def merge(state, slot_state, i):

@@ -1,9 +1,8 @@
-"""Test bootstrap: put ``src`` on sys.path and install the jax compat shims
-before any test module imports mesh machinery.
+"""Test bootstrap: put ``src`` on sys.path and load a hypothesis profile.
 
-Subprocess tests (test_perf_options / test_pipeline_parallel / the train
-driver) get the same treatment via ``src/sitecustomize.py`` — they export
-PYTHONPATH=src themselves, which auto-imports it at interpreter start-up.
+The profile replays the same examples on every run (``derandomize``) and
+keeps no example database; hypothesis' other caches go to the temporary
+directory, so a run writes nothing into the checkout.
 
 The CI matrix selects a kernel datapath per leg via REPRO_KERNEL_BACKEND
 (off | int8); tests read it through the ``kernel_backend`` fixture below so
@@ -12,21 +11,19 @@ the no-kernel and int8 paths are both exercised on every push.
 import os
 import pathlib
 import sys
+import tempfile
 
 import pytest
+from hypothesis import configuration, settings
 
 SRC = str(pathlib.Path(__file__).resolve().parent.parent / "src")
 if SRC not in sys.path:
     sys.path.insert(0, SRC)
 
-import repro.util.jaxcompat  # noqa: E402,F401
-
-# The pinned container has no hypothesis wheel; fall back to the vendored
-# deterministic mini-implementation so the property tests still execute.
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent / "_vendor"))
+configuration.set_hypothesis_home_dir(
+    pathlib.Path(tempfile.gettempdir()) / "hypothesis")
+settings.register_profile("repro", database=None, derandomize=True)
+settings.load_profile("repro")
 
 
 @pytest.fixture(scope="session")

@@ -1,8 +1,7 @@
 """Property tests for the fixed-point (I,F) quantizers.
 
-Runs under real hypothesis when installed, else the vendored
-deterministic fallback (tests/_vendor/hypothesis.py — see conftest.py).
-Each property is the algebraic contract the search/anneal/export
+Runs under hypothesis with the suite's derandomized profile (see
+conftest.py).  Each property is the algebraic contract the search/anneal/export
 subsystem builds on:
 
   * idempotence — a value already on the (I,F) grid is a fixed point of

@@ -1,4 +1,4 @@
-"""Property tests (hypothesis; vendored fallback in tests/_vendor) for the
+"""Property tests (hypothesis) for the
 ``Schedule`` tick tables over random (S, M) pairs.
 
 Three invariants of every schedule's plan:

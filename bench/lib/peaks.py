@@ -1,0 +1,19 @@
+"""Published peaks per chip, keyed by JAX's ``device_kind``.
+
+A device that is not in ``peaks.json`` is an error, never a default: a
+share of a guessed peak would read as a measurement.
+"""
+from __future__ import annotations
+
+import json
+import pathlib
+
+PEAKS_FILE = pathlib.Path(__file__).with_name("peaks.json")
+
+
+def peaks_for(device_kind: str) -> dict:
+    table = json.loads(PEAKS_FILE.read_text())
+    if device_kind not in table:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(table)}")
+    return table[device_kind]

@@ -36,6 +36,7 @@ from repro.core.taxonn import (
 from repro.kernels.ops import kernel_backend_ctx, resolve_backend
 from repro.quant.fixed_point import quantize_ste
 from repro.util.scan import xscan
+from repro.util.scopes import scoped
 from repro.models import blocks as B
 from repro.models import layers as L
 from repro.models import lm
@@ -186,6 +187,10 @@ def apply_resume_extra(extra: dict, cfg: ModelConfig,
 
 def _make_body(cfg: ModelConfig, positions, enc_out_in_shared: bool = False,
                moe_aux_parts: bool = False):
+    return scoped("block", _family_body(cfg, positions, moe_aux_parts))
+
+
+def _family_body(cfg: ModelConfig, positions, moe_aux_parts: bool):
     fam = cfg.family
 
     if fam in ("dense", "moe", "vlm"):
@@ -223,7 +228,7 @@ def _make_body(cfg: ModelConfig, positions, enc_out_in_shared: bool = False,
 def _enc_body(cfg: ModelConfig, positions):
     def body(p, shared, x, b_l):
         return B.transformer_block(p, x, cfg, positions, causal=False)
-    return body
+    return scoped("block", body)
 
 
 # ---------------------------------------------------------------------------
@@ -242,7 +247,7 @@ def _embed_fn(cfg: ModelConfig, batch, policy: QuantPolicy, bits0):
             p["mm_proj"] = bnd["mm_proj"]
         x0, _ = lm.embed_input(p, cfg, batch)
         return x0
-    return f
+    return scoped("embed", f)
 
 
 def _head_fn(cfg: ModelConfig, batch, policy: QuantPolicy, bits_last,
@@ -259,7 +264,7 @@ def _head_fn(cfg: ModelConfig, batch, policy: QuantPolicy, bits_last,
                                      bits_last["enabled"], True)
         loss, metrics = lm.ce_from_weight(w, cfg, x, batch["labels"])
         return loss, metrics
-    return f
+    return scoped("head_loss", f)
 
 
 def _bits_edge(bits, idx):
@@ -822,7 +827,8 @@ def _make_train_step(cfg: ModelConfig, policy: Optional[QuantPolicy],
         loss, head_vjp, metrics = jax.vjp(head_f, bnd, x_final, has_aux=True)
         d_bnd_head, G_final = head_vjp(jnp.asarray(scale, jnp.float32))
         metrics["aux"] = aux_sum
-        metrics["loss_total"] = loss + AUX_COEF * aux_sum
+        with jax.named_scope("head_loss"):
+            metrics["loss_total"] = loss + AUX_COEF * aux_sum
 
         # ---- the G-chain: reverse scan with fused per-layer updates ------
         if pipe_exec:
@@ -855,12 +861,14 @@ def _make_train_step(cfg: ModelConfig, policy: Optional[QuantPolicy],
 
         # ---- shared-attn update (hybrid) ---------------------------------
         if fam == "hybrid":
-            d_shared_params = jax.tree.map(lambda g: g / scale, dshared[0])
-            new_params["shared_attn"], new_opt["shared_attn"] = apply_update(
-                params["shared_attn"], d_shared_params,
-                opt_state["shared_attn"], hyper, optim_cfg)
-            gsq = gsq + sum(jnp.sum(jnp.square(g))
-                            for g in jax.tree.leaves(d_shared_params))
+            with jax.named_scope("update"):
+                d_shared_params = jax.tree.map(lambda g: g / scale,
+                                               dshared[0])
+                new_params["shared_attn"], new_opt["shared_attn"] = \
+                    apply_update(params["shared_attn"], d_shared_params,
+                                 opt_state["shared_attn"], hyper, optim_cfg)
+                gsq = gsq + sum(jnp.sum(jnp.square(g))
+                                for g in jax.tree.leaves(d_shared_params))
 
         # ---- encoder backward (encdec) ------------------------------------
         d_bnd_enc = None
@@ -881,22 +889,24 @@ def _make_train_step(cfg: ModelConfig, policy: Optional[QuantPolicy],
 
         # ---- boundary updates (embed gets head + input contributions) ----
         (d_bnd_embed,) = embed_vjp(G_in)
-        d_bnd = jax.tree.map(
-            lambda a, b: (a.astype(jnp.float32) + b.astype(jnp.float32)) / scale,
-            d_bnd_head, d_bnd_embed)
-        if d_bnd_enc is not None:
-            d_bnd = jax.tree.map(lambda a, b: a + b.astype(jnp.float32),
-                                 d_bnd, d_bnd_enc)
-        bnd_new, bnd_opt_new = {}, {}
-        for k in bnd_keys:
-            bnd_new[k], bnd_opt_new[k] = apply_update(
-                bnd[k], d_bnd[k], opt_state[k], hyper, optim_cfg)
-            gsq = gsq + sum(jnp.sum(jnp.square(g))
-                            for g in jax.tree.leaves(d_bnd[k]))
+        with jax.named_scope("update"):
+            d_bnd = jax.tree.map(
+                lambda a, b: (a.astype(jnp.float32)
+                              + b.astype(jnp.float32)) / scale,
+                d_bnd_head, d_bnd_embed)
+            if d_bnd_enc is not None:
+                d_bnd = jax.tree.map(lambda a, b: a + b.astype(jnp.float32),
+                                     d_bnd, d_bnd_enc)
+            bnd_new, bnd_opt_new = {}, {}
+            for k in bnd_keys:
+                bnd_new[k], bnd_opt_new[k] = apply_update(
+                    bnd[k], d_bnd[k], opt_state[k], hyper, optim_cfg)
+                gsq = gsq + sum(jnp.sum(jnp.square(g))
+                                for g in jax.tree.leaves(d_bnd[k]))
+            metrics["grad_norm"] = jnp.sqrt(gsq)
         new_params.update(bnd_new)
         new_opt.update(bnd_opt_new)
 
-        metrics["grad_norm"] = jnp.sqrt(gsq)
         metrics.update(pipe_metrics)
         return new_params, new_opt, metrics
 

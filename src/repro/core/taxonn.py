@@ -40,6 +40,7 @@ from repro.dist.async_collectives import (all_gather_chunks, group_size,
 from repro.dist.collectives import compressed_psum
 from repro.optim import OptimizerConfig, Hyper, apply_update
 from repro.util.scan import xscan
+from repro.util.scopes import scoped
 from repro.quant.fixed_point import (
     BitSchedule,
     make_bit_schedule,
@@ -270,8 +271,9 @@ def forward_stack(body_fn: Callable, stacked: PyTree, shared: PyTree,
         y, aux = body_fn(wq, sq, xq, b_l)
         return y, (xq, aux)
 
-    x_final, (caches, auxs) = xscan(fwd, x0, (stacked, _bits_xs(bits)))
-    return x_final, caches, jnp.sum(auxs)
+    with jax.named_scope("block"):
+        x_final, (caches, auxs) = xscan(fwd, x0, (stacked, _bits_xs(bits)))
+        return x_final, caches, jnp.sum(auxs)
 
 
 # ---------------------------------------------------------------------------
@@ -389,7 +391,7 @@ def _make_blocking_layer_update(policy: QuantPolicy, hyper: Hyper,
         # sgd is stateless (sharded_ok implies it): opt_l passes through
         return jax.tree.unflatten(ptd, new_leaves), opt_l, gsq, gsq_sh
 
-    return update_layer, uses_sharded
+    return scoped("update", update_layer), uses_sharded
 
 
 def _overlapped_update_helpers(policy: QuantPolicy, hyper: Hyper,
@@ -460,7 +462,8 @@ def _overlapped_update_helpers(policy: QuantPolicy, hyper: Hyper,
             lambda f, y: jnp.concatenate([f, y[:-depth]], axis=0),
             stackf, ys)
 
-    return start, finalize, pending0, drain, align
+    return tuple(scoped("update", f)
+                 for f in (start, finalize, pending0, drain, align))
 
 
 def backward_stack(body_fn: Callable, stacked: PyTree, shared: PyTree,
@@ -556,19 +559,21 @@ def backward_stack(body_fn: Callable, stacked: PyTree, shared: PyTree,
                 elif policy.dw_psum_axes:
                     g = lax.psum(g, policy.dw_psum_axes)
                 return _quant_update(g, b_l, key)
-            dW = jax.tree.map(prep, dW)
-
-            new_p, new_opt = apply_update(p_l, dW, opt_l, hyper, optim_cfg)
-            gsq = gsq + sum(jnp.sum(jnp.square(g))
-                            for g in jax.tree.leaves(dW))
+            with jax.named_scope("update"):
+                dW = jax.tree.map(prep, dW)
+                new_p, new_opt = apply_update(p_l, dW, opt_l, hyper,
+                                              optim_cfg)
+                gsq = gsq + sum(jnp.sum(jnp.square(g))
+                                for g in jax.tree.leaves(dW))
             dshared_acc = jax.tree.map(
                 lambda a, d: a + d.astype(jnp.float32), dshared_acc, dS)
             return (G_next, dshared_acc, gsq), (new_p, new_opt)
 
         xs = (stacked, opt_stacked, caches, _bits_xs(bits),
               jnp.arange(n_units, dtype=jnp.int32))
-        (G_in, dshared, gsq), (new_stacked, new_opt) = xscan(
-            bwd, (G_out, shared_f32, jnp.float32(0.0)), xs, reverse=True)
+        with jax.named_scope("gchain"):
+            (G_in, dshared, gsq), (new_stacked, new_opt) = xscan(
+                bwd, (G_out, shared_f32, jnp.float32(0.0)), xs, reverse=True)
         return G_in, new_stacked, new_opt, dshared, gsq
 
     # ---- communication-overlapped software pipeline ----------------------
@@ -599,9 +604,10 @@ def backward_stack(body_fn: Callable, stacked: PyTree, shared: PyTree,
 
         xs = (stacked, opt_stacked, caches, _bits_xs(bits),
               jnp.arange(n_units, dtype=jnp.int32))
-        (G_in, dshared, gsq, gsq_sh), (new_stacked, new_opt) = xscan(
-            bwd, (G_out, shared_f32, jnp.float32(0.0), jnp.float32(0.0)),
-            xs, reverse=True)
+        with jax.named_scope("gchain"):
+            (G_in, dshared, gsq, gsq_sh), (new_stacked, new_opt) = xscan(
+                bwd, (G_out, shared_f32, jnp.float32(0.0), jnp.float32(0.0)),
+                xs, reverse=True)
         if uses_sharded:
             # sharded leaves squared only this device's chunk
             gsq = gsq + lax.psum(gsq_sh, policy.dw_psum_axes)
@@ -630,10 +636,11 @@ def backward_stack(body_fn: Callable, stacked: PyTree, shared: PyTree,
 
     xs = (stacked, opt_stacked, caches, _bits_xs(bits),
           jnp.arange(n_units, dtype=jnp.int32))
-    (G_in, dshared, gsq, pending), (fin_stacked, fin_opt) = xscan(
-        bwd, (G_out, shared_f32, jnp.float32(0.0),
-              _pending0(stacked, opt_stacked, _bits_xs(bits))), xs,
-        reverse=True)
+    with jax.named_scope("gchain"):
+        (G_in, dshared, gsq, pending), (fin_stacked, fin_opt) = xscan(
+            bwd, (G_out, shared_f32, jnp.float32(0.0),
+                  _pending0(stacked, opt_stacked, _bits_xs(bits))), xs,
+            reverse=True)
     # drain: layers depth-1..0's reduces are still in flight after the scan
     flushes, gsq_f = _drain(pending)
     return (G_in, _align([f[0] for f in flushes], fin_stacked),
@@ -699,8 +706,8 @@ def apply_stacked_updates(stacked: PyTree, dW: PyTree, opt_stacked: PyTree,
             gsq = sum(jnp.sum(jnp.square(g)) for g in jax.tree.leaves(g_l))
             return new_p, new_s, gsq
 
-        new_p, new_s, gsqs = jax.vmap(upd)(stacked, dW, opt_stacked, bxs,
-                                           idxs)
+        new_p, new_s, gsqs = jax.vmap(scoped("update", upd))(
+            stacked, dW, opt_stacked, bxs, idxs)
         return new_p, new_s, jnp.sum(gsqs)
 
     decisions = _dw_leaf_transports(policy, stacked)

@@ -266,6 +266,7 @@ def bp_fused_unit(g: jax.Array, w: jax.Array, x: jax.Array, z: jax.Array,
                    pltpu.VMEM((din, dout), jnp.int8),
                    pltpu.VMEM((1, 1), jnp.float32)] + db_sem,
                 compiler_params=params, interpret=interpret,
+                name="bp_fused_unit",
             )(g, w, x, z, meta)
         return pl.pallas_call(
             functools.partial(_kernel_int8, n_k=n_k, g_bits=g_bits,
@@ -279,6 +280,7 @@ def bp_fused_unit(g: jax.Array, w: jax.Array, x: jax.Array, z: jax.Array,
                             pltpu.VMEM((din, dout), jnp.int8),
                             pltpu.VMEM((1, 1), jnp.float32)],
             compiler_params=params, interpret=interpret,
+            name="bp_fused_unit",
         )(g, w, x, z, meta)
 
     assert datapath == "emulate", datapath
@@ -295,6 +297,7 @@ def bp_fused_unit(g: jax.Array, w: jax.Array, x: jax.Array, z: jax.Array,
             + [pltpu.VMEM((din, dout), jnp.float32),
                pltpu.VMEM((din, dout), jnp.float32)] + db_sem,
             compiler_params=params, interpret=interpret,
+            name="bp_fused_unit",
         )(g, w, x, z, lr_arr)
     return pl.pallas_call(
         functools.partial(_kernel, n_k=n_k, g_bits=g_bits, w_bits=w_bits,
@@ -306,4 +309,5 @@ def bp_fused_unit(g: jax.Array, w: jax.Array, x: jax.Array, z: jax.Array,
         scratch_shapes=[pltpu.VMEM((din, dout), jnp.float32),
                         pltpu.VMEM((din, dout), jnp.float32)],
         compiler_params=params, interpret=interpret,
+        name="bp_fused_unit",
     )(g, w, x, z, lr_arr)

@@ -204,6 +204,7 @@ def bp_gstep(g: jax.Array, w: jax.Array, z: Optional[jax.Array], *,
                 scratch_shapes=db_scratch + [pltpu.VMEM((bm, bn), jnp.int32)]
                 + db_sem,
                 compiler_params=params, interpret=interpret,
+                name="bp_gstep",
             )(*args)
         in_specs = [g_spec, w_spec]
         args = [g, w]
@@ -227,6 +228,7 @@ def bp_gstep(g: jax.Array, w: jax.Array, z: Optional[jax.Array], *,
             out_shape=out_shape,
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
             compiler_params=params, interpret=interpret,
+            name="bp_gstep",
         )(*args)
 
     assert datapath == "emulate", datapath
@@ -250,6 +252,7 @@ def bp_gstep(g: jax.Array, w: jax.Array, z: Optional[jax.Array], *,
             kern_db, grid=grid, in_specs=in_specs, out_specs=o_spec,
             out_shape=out_shape, scratch_shapes=db_scratch + db_sem,
             compiler_params=params, interpret=interpret,
+            name="bp_gstep",
         )(*args)
     in_specs = [g_spec, w_spec]
     args = [g, w]
@@ -268,4 +271,5 @@ def bp_gstep(g: jax.Array, w: jax.Array, z: Optional[jax.Array], *,
     return pl.pallas_call(
         kern, grid=grid, in_specs=in_specs, out_specs=o_spec,
         out_shape=out_shape, compiler_params=params, interpret=interpret,
+        name="bp_gstep",
     )(*args)

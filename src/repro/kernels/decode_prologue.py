@@ -172,6 +172,7 @@ def _call_kernel(x2, nscale, wq2, wk2, wv2, wscales, biases, *, int8: bool,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=kops.interpret_mode("decode_prologue"),
+        name="decode_prologue",
     )(*args)
 
 

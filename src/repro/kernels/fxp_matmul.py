@@ -188,6 +188,7 @@ def fxp_matmul(x: jax.Array, w: jax.Array, *,
                                 pltpu.SemaphoreType.DMA((2, 2))],
                 compiler_params=params,
                 interpret=interpret,
+                name="fxp_matmul",
             )(x, w, meta)
         return pl.pallas_call(
             functools.partial(_kernel_int8, n_k=n_k, out_bits=out_bits,
@@ -199,6 +200,7 @@ def fxp_matmul(x: jax.Array, w: jax.Array, *,
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
             compiler_params=params,
             interpret=interpret,
+            name="fxp_matmul",
         )(x, w, meta)
 
     assert datapath == "emulate", datapath
@@ -216,6 +218,7 @@ def fxp_matmul(x: jax.Array, w: jax.Array, *,
                             pltpu.SemaphoreType.DMA((2, 2))],
             compiler_params=params,
             interpret=interpret,
+            name="fxp_matmul",
         )(x, w)
     return pl.pallas_call(
         functools.partial(_kernel, n_k=n_k, xa_bits=xa_bits, w_bits=w_bits,
@@ -226,4 +229,5 @@ def fxp_matmul(x: jax.Array, w: jax.Array, *,
         out_shape=out_shape,
         compiler_params=params,
         interpret=interpret,
+        name="fxp_matmul",
     )(x, w)

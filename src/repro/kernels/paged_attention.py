@@ -172,6 +172,7 @@ def _call_kernel(q, pool_l, tables, lens, groups: int, scale: float):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="paged_attention",
     )(tables.astype(jnp.int32), lens.astype(jnp.int32), *args)
 
 

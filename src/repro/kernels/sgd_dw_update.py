@@ -133,6 +133,7 @@ def sgd_dw_update(x: jax.Array, g: jax.Array, w: Optional[jax.Array], lr,
             out_shape=out_shape,
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
             compiler_params=params, interpret=interpret,
+            name="sgd_dw_update",
         )(*args)
 
     assert datapath == "emulate", datapath
@@ -156,4 +157,5 @@ def sgd_dw_update(x: jax.Array, g: jax.Array, w: Optional[jax.Array], lr,
     return pl.pallas_call(
         kern, grid=grid, in_specs=in_specs, out_specs=o_spec,
         out_shape=out_shape, compiler_params=params, interpret=interpret,
+        name="sgd_dw_update",
     )(*args)

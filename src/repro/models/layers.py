@@ -117,22 +117,25 @@ def _dense_unit(x, w, act, backend):
 
 
 def _dense_unit_fwd(x, w, act, backend):
-    shape = x.shape
-    x2 = x.reshape(-1, shape[-1])
-    z = kops.dense_fwd(x2, w, backend)              # f32 [M, N]
-    y = _act_fn(z, act).astype(x.dtype).reshape(shape[:-1] + (w.shape[1],))
+    with jax.named_scope("dense_unit"):
+        shape = x.shape
+        x2 = x.reshape(-1, shape[-1])
+        z = kops.dense_fwd(x2, w, backend)              # f32 [M, N]
+        y = _act_fn(z, act).astype(x.dtype).reshape(
+            shape[:-1] + (w.shape[1],))
     # z is a per-layer residual: under the engine's remat-per-layer backward
     # it lives only for one scan step (the paper's derivation-unit register)
     return y, (x2, w, z if act != "identity" else None, shape)
 
 
 def _dense_unit_bwd(act, backend, res, dy):
-    x2, w, z, shape = res
-    dy2 = dy.reshape(-1, dy.shape[-1]).astype(jnp.float32)
-    dz = dy2 if z is None else dy2 * _act_deriv(z, act)
-    dx = kops.dense_bwd_dx(dz, w, backend)               # Eq. 8 matmul leg
-    dw = kops.dense_bwd_dw(x2, dz, backend)              # Eq. 9 outer product
-    return dx.reshape(shape).astype(x2.dtype), dw.astype(w.dtype)
+    with jax.named_scope("dense_unit"):
+        x2, w, z, shape = res
+        dy2 = dy.reshape(-1, dy.shape[-1]).astype(jnp.float32)
+        dz = dy2 if z is None else dy2 * _act_deriv(z, act)
+        dx = kops.dense_bwd_dx(dz, w, backend)           # Eq. 8 matmul leg
+        dw = kops.dense_bwd_dw(x2, dz, backend)          # Eq. 9 outer product
+        return dx.reshape(shape).astype(x2.dtype), dw.astype(w.dtype)
 
 
 _dense_unit.defvjp(_dense_unit_fwd, _dense_unit_bwd)
@@ -143,8 +146,9 @@ def dense_unit(x, w, act: str = "identity",
     """act(x @ w) on the active kernel datapath. x: [..., K]; w: [K, N]."""
     backend = backend or kops.current_backend()
     if backend == "off":
-        return _act_fn((x @ w.astype(x.dtype)).astype(jnp.float32),
-                       act).astype(x.dtype)
+        with jax.named_scope("dense_unit"):
+            return _act_fn((x @ w.astype(x.dtype)).astype(jnp.float32),
+                           act).astype(x.dtype)
     return _dense_unit(x, w, act, backend)
 
 
@@ -317,16 +321,18 @@ def attention(params, x: Array, cfg: ModelConfig, positions: Array,
     b, t, _ = x.shape
     q, k, v = _project_qkv(params, x, cfg, positions)
     groups = q.shape[2] // cfg.num_kv_heads
-    kx = _expand_kv(k, groups)
-    vx = _expand_kv(v, groups)
     scale = cfg.head_dim ** -0.5
-    # §Perf "flash_attn": online-softmax at every length (never materialise
-    # the [B,H,T,T] score tensor); default only above the chunk threshold
-    if t > ATTN_CHUNK_THRESHOLD or (perf_opt("flash_attn") and t > 1024):
-        out = _sdpa_chunked(q, kx, vx, causal, cfg.swa_window, scale)
-    else:
-        mask = _attn_mask(t, t, causal, cfg.swa_window)
-        out = _sdpa_full(q, kx, vx, mask, scale)
+    with jax.named_scope("attention"):
+        kx = _expand_kv(k, groups)
+        vx = _expand_kv(v, groups)
+        # §Perf "flash_attn": online-softmax at every length (never
+        # materialise the [B,H,T,T] score tensor); default only above the
+        # chunk threshold
+        if t > ATTN_CHUNK_THRESHOLD or (perf_opt("flash_attn") and t > 1024):
+            out = _sdpa_chunked(q, kx, vx, causal, cfg.swa_window, scale)
+        else:
+            mask = _attn_mask(t, t, causal, cfg.swa_window)
+            out = _sdpa_full(q, kx, vx, mask, scale)
     wo = _masked_wo(params, cfg, dt)
     backend = kops.current_backend()
     if backend != "off":
@@ -453,11 +459,12 @@ def mla_attention(params, x: Array, cfg: ModelConfig, positions: Array,
         k_pe, (b, t, cfg.num_heads, dr))], axis=-1)
     qq = jnp.concatenate([q_nope, q_pe], axis=-1)
     scale = (dn + dr) ** -0.5
-    if t > ATTN_CHUNK_THRESHOLD:
-        out = _sdpa_chunked(qq, k, v, True, None, scale)
-    else:
-        mask = _attn_mask(t, t, True, None)
-        out = _sdpa_full(qq, k, v, mask, scale)
+    with jax.named_scope("attention"):
+        if t > ATTN_CHUNK_THRESHOLD:
+            out = _sdpa_chunked(qq, k, v, True, None, scale)
+        else:
+            mask = _attn_mask(t, t, True, None)
+            out = _sdpa_full(qq, k, v, mask, scale)
     y = jnp.einsum("bthk,hkd->btd", out, params["wo"].astype(dt))
     if return_cache:
         return y, (c_kv, k_pe[:, :, 0, :])

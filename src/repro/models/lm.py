@@ -217,6 +217,11 @@ def ce_loss_head(params, cfg: ModelConfig, x: Array, labels: Array):
 def ce_from_weight(w: Array, cfg: ModelConfig, x: Array, labels: Array):
     """CE head given an explicit [D, V] output weight (used by the TaxoNN
     engine, which differentiates the head separately)."""
+    with jax.named_scope("head_loss"):
+        return _chunked_ce(w, cfg, x, labels)
+
+
+def _chunked_ce(w: Array, cfg: ModelConfig, x: Array, labels: Array):
     bsz, t, d = x.shape
     c = min(cfg.logit_chunk, t)
     n = (t + c - 1) // c

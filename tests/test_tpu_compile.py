@@ -11,6 +11,7 @@ The topology is described inside a fixture (never at import): only one
 process at a time may load the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -259,3 +260,38 @@ def test_decode_prologue_compiles(compile_tpu, int8):
                                (bq, bk, bv), int8=int8,
                                eps=float(QWEN.norm_eps))
     compile_tpu(fn, *avals)
+
+
+def _kernel_call(name):
+    """The kernel's int8 call at the first grid of its role in the train
+    step, as (fn, *avals)."""
+    role = "dw" if name == "sgd_dw_update" else "fwd"
+    m, n, k = _dense_shapes(role)[0]
+    bm, bn, bk = kops.tune_blocks(m, n, k, itemsize=1)
+    kw = dict(bm=bm, bn=bn, bk=bk, datapath="int8")
+    s8 = jnp.int8
+    if name == "fxp_matmul":
+        return (lambda x, w, s: fxp_matmul(x, w, scale=s, out_bits=None,
+                                           **kw),
+                _s((m, k), s8), _s((k, n), s8), _scalar())
+    if name == "bp_gstep":
+        return (lambda g, w, s: bp_gstep(g, w, None, g_bits=None,
+                                         act="identity", scale=s, **kw),
+                _s((m, k), s8), _s((n, k), s8), _scalar())
+    return (lambda x, g, s: sgd_dw_update(x, g, None, 0.0, scale=s, **kw),
+            _s((k, m), s8), _s((k, n), s8), _scalar())
+
+
+@pytest.mark.parametrize("name", ["fxp_matmul", "bp_gstep", "sgd_dw_update"])
+def test_kernel_carries_its_name(compile_tpu, name):
+    """The Mosaic call of each dense-unit kernel is named after the kernel
+    in the compiled program (its instruction and its op_name), so that its
+    device time can be found by name."""
+    fn, *avals = _kernel_call(name)
+    text = compile_tpu(fn, *avals)
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
+             and " custom-call(" in ln]
+    assert calls
+    for ln in calls:
+        assert re.match(rf"\s*(ROOT )?%{name}(\.\d+)? = ", ln), ln[:200]
+        assert re.search(rf'op_name="[^"]*/{name}/[^"]*"', ln), ln[:200]

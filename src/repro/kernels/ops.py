@@ -72,8 +72,11 @@ def _on_cpu() -> bool:
 # kernel ran in the Pallas interpreter), "no_tile" (no aligned block
 # divides a dim: jnp reference), "sharded" (traced under a multi-device
 # mesh: jnp reference, which XLA partitions) and "over_vmem" (the resident
-# frame does not fit the VMEM budget: jnp reference).  Drivers print them
-# at the end of a run.
+# frame does not fit the VMEM budget: jnp reference).  Attention's flash
+# kernel never runs interpreted: off a TPU ("interpret"), over a T that is
+# not a multiple of 128 ("unaligned"), without a causal mask
+# ("bidirectional") or under a mesh ("sharded") the materialised jnp path
+# runs.  Drivers print them at the end of a run.
 
 KERNEL_TRACES: collections.Counter = collections.Counter()
 

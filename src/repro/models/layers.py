@@ -3,7 +3,9 @@
 Pure functions over explicit parameter pytrees (no flax).  Every ``apply``
 comes with a matching ``init``.  Layers support three execution modes:
 
-  * full-sequence (training / prefill, causal or bidirectional mask)
+  * full-sequence (training / prefill, causal or bidirectional mask); on
+    a TPU, causal attention over an aligned T runs the Pallas flash kernels
+    of ``kernels.flash_attention`` (scores never leave VMEM)
   * chunked online-softmax attention for long sequences (flash-style, pure
     JAX ``lax.scan`` over KV blocks — bounded memory at 32k+)
   * single-token decode against a KV cache (GQA ring-buffer for SWA, MLA
@@ -20,6 +22,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.dist.api import constrain, model_axis_size_ctx, perf_opt
+from repro.kernels import flash_attention as FA
 from repro.kernels import ops as kops
 from repro.kernels.common import act_deriv as _act_deriv, act_fn as _act_fn
 from repro.models.config import ModelConfig
@@ -330,6 +333,11 @@ def attention(params, x: Array, cfg: ModelConfig, positions: Array,
         # chunk threshold
         if t > ATTN_CHUNK_THRESHOLD or (perf_opt("flash_attn") and t > 1024):
             out = _sdpa_chunked(q, kx, vx, causal, cfg.swa_window, scale)
+        elif FA.flash_path(t, causal):
+            # the Pallas flash kernels (TPU): scores stay in VMEM, and the
+            # KV heads are read per group, not repeated
+            out = FA.flash_attention(q, k, v, scale=scale,
+                                     window=cfg.swa_window)
         else:
             mask = _attn_mask(t, t, causal, cfg.swa_window)
             out = _sdpa_full(q, kx, vx, mask, scale)

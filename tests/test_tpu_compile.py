@@ -22,6 +22,7 @@ from repro.configs import get_config
 from repro.configs.lenet5 import LeNetConfig
 from repro.core.lenet import init_lenet_params, lenet_bits, make_lenet_train_step
 from repro.kernels import decode_prologue as DP
+from repro.kernels import flash_attention as FA
 from repro.kernels import ops as kops
 from repro.kernels import paged_attention as PA
 from repro.kernels.bp_fused_unit import bp_fused_unit
@@ -30,6 +31,7 @@ from repro.kernels.fxp_matmul import fxp_matmul
 from repro.kernels.sgd_dw_update import sgd_dw_update
 
 QWEN = get_config("qwen1.5-0.5b")
+DANUBE = get_config("h2o-danube-3-4b")
 TRAIN_BATCH, TRAIN_SEQ = 8, 1024          # the chip smoke train phase
 SERVE_SLOTS = 8                           # the chip smoke serve phase
 LENET = LeNetConfig()                     # the paper's own network
@@ -295,3 +297,40 @@ def test_kernel_carries_its_name(compile_tpu, name):
     for ln in calls:
         assert re.match(rf"\s*(ROOT )?%{name}(\.\d+)? = ", ln), ln[:200]
         assert re.search(rf'op_name="[^"]*/{name}/[^"]*"', ln), ln[:200]
+
+
+# The benchmark's train cells: [B, T] and the model's heads.
+FLASH_CELLS = {"qwen": (QWEN, 16, 1024), "danube": (DANUBE, 1, 4096)}
+
+
+@pytest.mark.parametrize("vjp", [False, True], ids=["fwd", "vjp"])
+@pytest.mark.parametrize("cell", list(FLASH_CELLS))
+def test_flash_attention_compiles(compile_tpu, cell, vjp):
+    """The flash-attention kernels at the train cells' shapes (qwen MHA
+    head 64; danube GQA 32/8, head 120 padded to 128), forward alone and
+    forward with its vjp, each Mosaic call under its kernel's name."""
+    cfg, b, t = FLASH_CELLS[cell]
+    h, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    bf16 = jnp.bfloat16
+
+    def attend(q, k, v):
+        return FA.flash_attention(q, k, v, scale=hd ** -0.5,
+                                  window=cfg.swa_window)
+
+    if vjp:
+        def fn(q, k, v, do):
+            out, back = jax.vjp(attend, q, k, v)
+            return out, back(do)
+        names = ["flash_attention", "flash_attention_dq",
+                 "flash_attention_dkv"]
+        avals = [_s((b, t, h, hd), bf16), _s((b, t, hkv, hd), bf16),
+                 _s((b, t, hkv, hd), bf16), _s((b, t, h, hd), bf16)]
+    else:
+        fn, names = attend, ["flash_attention"]
+        avals = [_s((b, t, h, hd), bf16), _s((b, t, hkv, hd), bf16),
+                 _s((b, t, hkv, hd), bf16)]
+    text = compile_tpu(fn, *avals)
+    calls = sorted(re.match(r"\s*(?:ROOT )?%([\w.]+) = ", ln).group(1)
+                   for ln in text.splitlines()
+                   if "tpu_custom_call" in ln and " custom-call(" in ln)
+    assert [c.split(".")[0] for c in calls] == sorted(names), calls

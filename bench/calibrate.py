@@ -30,12 +30,12 @@ def emit(**kw):
     print(json.dumps(kw), flush=True)
 
 
-def train(m, traffic, seeds, controls):
+def train(m, traffic, seeds, controls, family):
     run = None
     for seed in dict.fromkeys(seeds + controls):
         t0 = time.perf_counter()
         if run is None:
-            run = TrainRun(m, traffic, seed, log)
+            run = TrainRun(m, traffic, seed, log, family)
             run.setup()
         else:
             run.start(seed)
@@ -71,7 +71,7 @@ def main(argv=None) -> int:
     traffic = spec.traffic(cell["traffic"])
     seeds = [int(s) for s in args.seeds.split(",") if s]
     controls = [int(s) for s in args.control_seeds.split(",") if s]
-    train(m, traffic, seeds, controls)
+    train(m, traffic, seeds, controls, spec.family(m))
     return 0
 
 

@@ -17,8 +17,8 @@ dense_unit/fxp_matmul/pallas_call``.  The layers, from the program's
 A device trace names an op by its HLO instruction ("%fusion.12 = ...").
 Where the event's name carries no ``op_name``, the path is looked up by
 instruction name in the optimised HLO text of the cell's step
-(``step_hlo``): the same step lowered again from the cell's shapes, which
-the window's own compile left in the persistent compilation cache.  An
+(``step_hlo``): the run's own step lowered again from its state's shapes,
+which the window's own compile left in the persistent compilation cache.  An
 instruction the compiler made without an op_name takes a neighbour's
 (``hlo_op_names``).
 
@@ -182,34 +182,10 @@ def _in_layers(trace: dict, window: tuple) -> bool:
 
 
 def step_hlo(rec: dict) -> str:
-    """The optimised HLO text of the cell's train step, lowered from its
-    shapes as ``train_loop.TrainRun.setup`` builds it."""
-    import jax
-    from bench.lib import train_loop, weights
-    from repro.core import QuantPolicy, StepOptions, make_train_step
-    from repro.core.steps import default_bits, init_train_state
-    from repro.optim import Hyper
-
-    m, t = rec["model"], rec["traffic"]
-    run = train_loop.TrainRun(m, t, 0, None)
-    step = make_train_step(
-        run.cfg, QuantPolicy.off(), run.ocfg,
-        StepOptions(engine=t["engine"], kernel_backend=t["kernel_backend"]))
-
-    def state(key):
-        p = weights.stacked_params(key, m)
-        return p, init_train_state(p, run.ocfg)
-
-    def shape(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-                            tree)
-    params, opt = jax.eval_shape(state, weights.seed_key(0))
-    batch = train_loop.make_batches(m, dict(t, distinct_batches=1), 0)[0]
-    hyper = Hyper(lr=jax.ShapeDtypeStruct((), "float32"),
-                  step=jax.ShapeDtypeStruct((), "int32"))
-    bits = default_bits(run.cfg, enabled=False)
-    return jax.jit(step, donate_argnums=(0, 1)).lower(
-        params, opt, shape(batch), hyper, shape(bits)).compile().as_text()
+    """The optimised HLO text of the record's train step: the window's own
+    jitted step, lowered from its state's shapes by the run that built it
+    (``train_loop.TrainRun.lowered``)."""
+    return rec["run"].lowered().compile().as_text()
 
 
 def scoped_trace(rec: dict):

@@ -7,11 +7,13 @@ file of its own, looked up by name:
     bench/traffic/<traffic>.json           parameters of the traffic mix
     bench/limits/<workload>.json           the correctness limits of a cell
     bench/metrics/<metric>.py              the reader of a per-layer metric
+    bench/families/<family>.py             a model family's weights,
+                                           reference and counts
 
 Lookups search the data root first (the directory of the BENCHMARK.json
 in use) and then this checkout, so a cell defined in another directory can
-reuse the files here.  Adding a cell, a mix or a metric means adding files
-and entries, never editing code.
+reuse the files here.  Adding a cell, a mix, a metric or a model family
+means adding files and entries, never editing code.
 """
 from __future__ import annotations
 
@@ -63,10 +65,21 @@ class Spec:
                 if workload in m.get("workloads", [workload])
                 and m["moves"] in e2e]
 
-    def reader(self, metric: str):
-        path = self._find(f"bench/metrics/{metric}.py")
+    def _module(self, rel: str, prefix: str, name: str):
+        path = self._find(rel)
         spec = importlib.util.spec_from_file_location(
-            "bench_metric_" + metric.replace(".", "_").replace("-", "_"), path)
+            prefix + name.replace(".", "_").replace("-", "_"), path)
         mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(mod)
-        return mod.read
+        return mod
+
+    def reader(self, metric: str):
+        return self._module(f"bench/metrics/{metric}.py", "bench_metric_",
+                            metric).read
+
+    def family(self, model: dict):
+        """The module of ``model["family"]`` (``bench/families/dense.py``
+        says what it holds)."""
+        name = model["family"]
+        return self._module(f"bench/families/{name}.py", "bench_family_",
+                            name)

@@ -1,16 +1,17 @@
 """The training traffic: one compiled train step driven from the seed.
 
 Set-up builds the program's jitted ``make_train_step`` step and its state
-(weights from ``bench.lib.weights`` and the optimizer state, in one jitted
-call), makes the traffic's distinct Markov batches, and drives the step
-through its first ``check_steps`` steps with the window's own call and
-feed.  Those steps compile the step and give the program's readings: each
+(the model family's weights from ``bench.lib.weights`` and the optimizer
+state, in one jitted call), makes the traffic's distinct Markov batches,
+and drives the step through its first ``check_steps`` steps with the
+window's own call and feed.  Those steps compile the step and give the program's readings: each
 step's loss, the per-leaf norm of the first gradient (the momentum after
 one step, which starts at zero) and the per-leaf norm of the weights'
 change after the last checked step.  The window then runs the same object
 for ``seconds``; every step ends in ``block_until_ready``.  After the
 window the state is freed and the plain reference replays the checked
-steps.
+steps.  The same builder gives the step and its state's shapes to
+``lowered``, which the scope reader compiles again after the window.
 """
 from __future__ import annotations
 
@@ -23,7 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench.lib import weights as W
-from bench.lib.flops import dims
 from bench.lib.markov import markov_batch
 from bench.lib.reference import ReferenceTrainer, leaf_items
 
@@ -45,13 +45,6 @@ def make_batches(m: dict, traffic: dict, seed: int) -> list:
             for i in range(traffic["distinct_batches"])]
 
 
-def build_state(m: dict, ocfg: OptimizerConfig, seed: int):
-    def init(key):
-        p = W.stacked_params(key, m)
-        return p, init_train_state(p, ocfg)
-    return jax.jit(init)(W.seed_key(seed))
-
-
 def _norm(x):
     return jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32))))
 
@@ -63,11 +56,11 @@ def _stack_norms(tree):
                                    axis=tuple(range(1, x.ndim)))), tree)
 
 
-def program_readers(m: dict):
+def program_readers(m: dict, family):
     """Jitted readers of the program's state: per-leaf norms of the first
     gradient (from the momentum) and of the change since the seed's
     weights (remade one layer at a time)."""
-    L = dims(m)["L"]
+    L = family.stacked_layers(m)
 
     def grad_norms(opt):
         out = {k: jax.tree.map(_norm, v["m"]) for k, v in opt.items()
@@ -76,13 +69,13 @@ def program_readers(m: dict):
         return out
 
     def change_norms(params, key):
-        p0 = W.boundary_params(key, m)
+        p0 = family.boundary_params(key, m)
         out = {k: jax.tree.map(lambda a, b: _norm(a - b), params[k], p0[k])
                for k in p0}
 
         def one(args):
             p_l, l = args
-            q = W.layer_params(W.layer_key(key, l), m)
+            q = family.layer_params(W.layer_key(key, l), m)
             return jax.tree.map(lambda a, b: _norm(a - b), p_l, q)
         out["blocks"] = jax.lax.map(one, (params["blocks"], jnp.arange(L)))
         return out
@@ -104,22 +97,43 @@ def flatten_readings(tree: dict) -> dict:
 
 
 class TrainRun:
-    """One process's training cell: ``setup()``, ``window()``, ``check()``."""
+    """One process's training cell: ``setup()``, ``window()``, ``check()``.
+    ``family`` is the model family's module (``Spec.family``)."""
 
-    def __init__(self, m: dict, traffic: dict, seed: int, log):
+    def __init__(self, m: dict, traffic: dict, seed: int, log, family):
         self.m, self.traffic, self.seed, self.log = m, traffic, seed, log
+        self.fam = family
         self.cfg = ModelConfig(**m)
         self.ocfg = optimizer(traffic)
         self.lr = float(traffic["lr"])
-
-    def setup(self):
-        t = self.traffic
+        t = traffic
         step = make_train_step(
             self.cfg, QuantPolicy.off(), self.ocfg,
             StepOptions(engine=t["engine"], kernel_backend=t["kernel_backend"]))
         self.step = jax.jit(step, donate_argnums=(0, 1))
         self.bits = default_bits(self.cfg, enabled=False)
-        self._readers = program_readers(self.m)
+
+    def init_state(self, key):
+        """The step's state from a key: the family's weights, stacked, and
+        the optimizer's state.  Jit it, or take its shapes."""
+        p = W.stacked_params(key, self.m, self.fam)
+        return p, init_train_state(p, self.ocfg)
+
+    def lowered(self):
+        """The window's step lowered from its arguments' shapes."""
+        def shape(tree):
+            return jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+        params, opt = jax.eval_shape(self.init_state, W.seed_key(0))
+        batch = make_batches(self.m, dict(self.traffic, distinct_batches=1),
+                             0)[0]
+        hyper = Hyper(lr=jax.ShapeDtypeStruct((), "float32"),
+                      step=jax.ShapeDtypeStruct((), "int32"))
+        return self.step.lower(params, opt, shape(batch), hyper,
+                               shape(self.bits))
+
+    def setup(self):
+        self._readers = program_readers(self.m, self.fam)
         self._refs = {}
         self.start(self.seed)
         # Python's collector runs here, over set-up's objects, and is off
@@ -137,7 +151,8 @@ class TrainRun:
         self.seed = seed
         self.host_batches = make_batches(self.m, t, self.seed)
         self.batches = [jax.device_put(b) for b in self.host_batches]
-        self.params, self.opt = build_state(self.m, self.ocfg, self.seed)
+        self.params, self.opt = jax.jit(self.init_state)(
+            W.seed_key(self.seed))
         grad_norms, change_norms = self._readers
         self.n = 0
         losses = []
@@ -201,8 +216,8 @@ class TrainRun:
         leaves out half of each batch (half the rows, or of a single row
         the later half of its tokens) and takes the mean over the rest."""
         if bits not in self._refs:
-            self._refs[bits] = ReferenceTrainer(self.m, self.lr,
-                                                self.ocfg.momentum, bits)
+            self._refs[bits] = ReferenceTrainer(
+                self.fam, self.m, self.lr, self.ocfg.momentum, bits)
         batches = self.host_batches[:self.traffic["check_steps"]]
         if half:
             b, t = batches[0]["tokens"].shape
@@ -218,6 +233,6 @@ def numbers(run: TrainRun) -> dict:
 
 
 def window_flops(run: TrainRun, result: dict) -> float:
-    from bench.lib.flops import train_flops_per_step
     t = run.traffic
-    return result["steps"] * train_flops_per_step(run.m, t["batch"], t["seq"])
+    return result["steps"] * run.fam.train_flops_per_step(
+        run.m, t["batch"], t["seq"])

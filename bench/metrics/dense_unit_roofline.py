@@ -25,7 +25,8 @@ def read(rec):
     k_s = trace_reader.kernel_s(trace, rec["trace_window"], KERNELS)
     if k_s <= 0:
         return None
-    grids = flops.dense_unit_grids(rec["model"], t["batch"] * t["seq"])
+    grids = rec["run"].fam.dense_unit_grids(rec["model"],
+                                            t["batch"] * t["seq"])
     least = rec["result"]["steps"] * flops.least_time_s(
         grids, peaks["int8_ops_per_s"], peaks["hbm_bytes_per_s"])
     return 100.0 * least / k_s

@@ -86,10 +86,10 @@ def enable_cache():
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 
-def make_run(kind: str, m: dict, traffic: dict, seed: int):
+def make_run(kind: str, m: dict, traffic: dict, seed: int, family):
     if kind == "train":
         from bench.lib import train_loop
-        return train_loop, train_loop.TrainRun(m, traffic, seed, log)
+        return train_loop, train_loop.TrainRun(m, traffic, seed, log, family)
     raise ValueError(f"unknown traffic kind {kind!r}")
 
 
@@ -103,10 +103,10 @@ def run_cell(args, require_tpu: bool = True) -> dict:
 
     spec = Spec(args.spec)
     cell = spec.workload(args.workload)
-    cfg_file = spec.config(cell["config"])
+    m = spec.config(cell["config"])["model"]
+    family = spec.family(m)
     traffic = spec.traffic(cell["traffic"])
     limits = spec.limits(args.workload)
-    m = cfg_file["model"]
     if require_tpu:
         devs = check_devices(cell["chips"])
         enable_cache()
@@ -114,7 +114,7 @@ def run_cell(args, require_tpu: bool = True) -> dict:
         devs = jax.devices()[:cell["chips"]]
     peaks = peaks_for(devs[0].device_kind) if require_tpu else None
     stats = CompileStats()
-    mod, run = make_run(traffic["kind"], m, traffic, args.seed)
+    mod, run = make_run(traffic["kind"], m, traffic, args.seed, family)
     run.setup()
     setup_s = time.perf_counter() - T_START
     before = stats.snapshot()
@@ -138,7 +138,7 @@ def run_cell(args, require_tpu: bool = True) -> dict:
         f"({after['compile_s'] - before['compile_s']:.3f} s)")
     device = device_info(devs)
 
-    record = {"model": m, "traffic": traffic, "peaks": peaks,
+    record = {"model": m, "run": run, "traffic": traffic, "peaks": peaks,
               "result": result, "window_s": result["window_s"],
               "flops": mod.window_flops(run, result), "trace": None}
     breakdown = None

@@ -41,7 +41,8 @@ def cell_parts(workload):
 
 def test_train_control_fails_the_limits():
     m, traffic, limits = cell_parts("tiny-dense.train_tiny")
-    r = train_loop.TrainRun(m, traffic, SEED, print)
+    r = train_loop.TrainRun(m, traffic, SEED, print,
+                            Spec(DATA / "BENCHMARK.json").family(m))
     r.setup()
     nums = compare.train_numbers(r.reference(bits=4), r.reference())
     ok, checks = compare.judge(nums, limits)

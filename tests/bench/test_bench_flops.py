@@ -1,12 +1,19 @@
 """The FLOP and byte counters, against the program's own parameter count
-for every configuration of the benchmark."""
+for every configuration of the benchmark, each through its family's
+module; and the dense family's counts at fixed sizes."""
 import dataclasses
 import json
+import math
 import pathlib
 
+import jax
 import pytest
 
+from bench.families import dense
 from bench.lib import flops
+from bench.lib import weights as W
+from bench.lib.reference import leaf_items
+from bench.lib.spec import Spec
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -17,29 +24,45 @@ def model(path):
     return json.loads((ROOT / path).read_text())["model"]
 
 
+def family(m):
+    return Spec(ROOT / "BENCHMARK.json").family(m)
+
+
 @pytest.mark.parametrize("path", CONFIGS)
 def test_param_count_matches_model_config(path):
     from repro.models.config import ModelConfig
     m = model(path)
-    assert flops.param_count(m) == ModelConfig(**m).param_count()
+    assert family(m).param_count(m) == ModelConfig(**m).param_count()
+
+
+def _not_matmul(name):
+    """Norm scales and biases, by the program's names for them."""
+    parts = name.split("/")
+    return (any(p.endswith("norm") for p in parts)
+            or parts[-1] in ("bq", "bk", "bv", "bias"))
 
 
 @pytest.mark.parametrize("path", CONFIGS)
 def test_matmul_params_exclude_norms_and_biases(path):
+    """The weights a token multiplies: the program's active parameters
+    less the family's norm scales and biases (counted from the weights it
+    makes), and less an untied input table, which is a lookup; the head
+    counts once."""
+    from repro.models.config import ModelConfig
     m = model(path)
-    d = flops.dims(m)
-    extra = d["L"] * 2 * d["D"] + d["D"]
-    if m.get("qkv_bias"):
-        extra += d["L"] * (d["H"] + 2 * d["Hkv"]) * d["hd"]
-    # the head is counted once; an untied input table is a lookup
-    if not m.get("tie_embeddings", True):
-        extra += d["V"] * d["D"]
-    assert flops.matmul_params(m) == flops.param_count(m) - extra
+    fam = family(m)
+    tree = jax.eval_shape(lambda: W.stacked_params(W.seed_key(0), m, fam))
+    extra = sum(math.prod(a.shape) for name, a in leaf_items(tree)
+                if _not_matmul(name))
+    if "lm_head" in tree:
+        extra += math.prod(tree["embed"].shape)
+    assert fam.matmul_params(m) == \
+        ModelConfig(**m).active_param_count() - extra
 
 
 def test_qwen_flops_per_trained_token():
     m = model("bench/configs/qwen1.5-0.5b.json")
-    per_tok = flops.train_flops_per_step(m, 16, 1024) / (16 * 1024)
+    per_tok = family(m).train_flops_per_step(m, 16, 1024) / (16 * 1024)
     assert per_tok == pytest.approx(2.934e9, rel=1e-3)
 
 
@@ -52,7 +75,7 @@ def test_keys_attended_causal_and_windowed():
 def test_dense_unit_grids_and_least_time():
     m = {"d_model": 8, "num_heads": 2, "num_kv_heads": 1, "d_ff": 16,
          "vocab_size": 32, "num_layers": 2}
-    grids = flops.dense_unit_grids(m, rows=4)
+    grids = dense.dense_unit_grids(m, rows=4)
     assert len(grids) == 2 * 7 * 3
     assert grids[:3] == [(4, 8, 8), (4, 8, 8), (8, 8, 4)]
     # one grid, compute-bound vs bandwidth-bound

@@ -1,7 +1,7 @@
 """Device time by layer on a hand-built trace: scope paths from the event
 names or from the step's HLO text, the innermost-scope rule, loops left
 out, and the three per-layer readers; and the step's HLO text lowered
-again from a cell's shapes is the program the window ran."""
+again from the run's state shapes is the program the window ran."""
 import pathlib
 
 import jax
@@ -195,7 +195,7 @@ def test_readers_read_nothing_when_no_op_in_the_window_is_scoped(reader, name):
 @pytest.mark.parametrize("name", READERS)
 def test_readers_read_nothing_from_an_unscoped_program(reader, name):
     """Ops with no layer scope, and no step to look them up in (the
-    record names no model): nothing is read and nothing raises."""
+    record holds no run): nothing is read and nothing raises."""
     t = {"devices": {"/device:TPU:0": [("%fusion.1 = f32[8]{0} fusion()",
                                         0, MS)]},
          "spans": [("bench.window", 0, MS)]}
@@ -206,14 +206,15 @@ def test_step_hlo_is_the_program_the_window_ran():
     spec = Spec(DATA / "BENCHMARK.json")
     w = spec.workload("tiny-dense.train_tiny")
     m, t = spec.config(w["config"])["model"], spec.traffic(w["traffic"])
-    run = train_loop.TrainRun(m, t, 2 ** 31 + 5, lambda msg: None)
+    run = train_loop.TrainRun(m, t, 2 ** 31 + 5, lambda msg: None,
+                              spec.family(m))
     run.setup()
     ran = run.step.lower(run.params, run.opt, run.batches[0],
                          train_loop.Hyper(lr=jax.numpy.float32(run.lr),
                                           step=jax.numpy.int32(0)),
                          run.bits).compile().as_text()
     run.free()
-    text = S.step_hlo({"model": m, "traffic": t})
+    text = S.step_hlo({"run": run})
     assert S.hlo_op_names(text) == S.hlo_op_names(ran)
     layers = {S.innermost(p) for p in S.hlo_op_names(text).values()}
     # kernel_backend "auto" runs no dense unit on the CPU
